@@ -41,6 +41,7 @@ import numpy as np
 from benchmarks.artifact import write_artifact
 from repro.distributed import LocalTransport, run_local_hosts
 from repro.rsp.dataset import RSPDataset
+from repro.runtime import use_compile_cache
 
 SPEEDUP_GATE = 1.5
 
@@ -185,6 +186,7 @@ def _verdict(gates: dict) -> list[str]:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument(
         "--smoke", action="store_true", help="CI sizes + hard pass/fail gate"

@@ -41,6 +41,7 @@ from repro.core.sampler import UniformPolicy, WeightedPolicy
 from repro.core.types import RSPSpec
 from repro.rsp.engine import BlockExecutor, MmapFetcher, StoreFetcher
 from repro.rsp.summaries import combine_summaries, summarize_block, summarize_blocks
+from repro.runtime import use_compile_cache
 
 
 class LatencyFetcher:
@@ -190,6 +191,7 @@ def engine_rows(smoke: bool = False, latency: float = 8e-3) -> list[tuple[str, f
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true", help="small sizes + hard pass/fail gate")
     ap.add_argument("--latency", type=float, default=8e-3,

@@ -42,6 +42,7 @@ import numpy as np
 
 from benchmarks.artifact import write_artifact
 from repro import rsp
+from repro.runtime import use_compile_cache
 
 
 def _chunk_stream(num_chunks: int, chunk_records: int, features: int, seed: int = 9):
@@ -149,6 +150,7 @@ def ingest_rows(smoke: bool = False) -> list[tuple[str, float, str]]:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes + hard pass/fail gate")

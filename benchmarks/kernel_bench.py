@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import interpret_mode, use_compile_cache
+
 Row = tuple  # (name, value, derived[, metrics dict])
 
 # the --smoke gate: fused one-pass filter+sketch vs the two-pass baseline
@@ -137,7 +139,9 @@ def bench_block_sketch() -> list[Row]:
     gb = n * f * 4 / 1e9
     us = _timeit(
         lambda: jax.block_until_ready(
-            block_sketch_pallas(x, lo, inv_w, bins=bins, tile_rows=512)[0]
+            block_sketch_pallas(
+                x, lo, inv_w, bins=bins, tile_rows=512, interpret=interpret_mode()
+            )[0]
         ),
         repeat=1,
     )
@@ -276,6 +280,7 @@ ALL_KERNELS = [
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument(
         "--smoke", action="store_true",

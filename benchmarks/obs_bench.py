@@ -41,6 +41,7 @@ import numpy as np
 
 from benchmarks.artifact import default_out_dir, write_artifact
 from repro import obs, rsp
+from repro.runtime import use_compile_cache
 
 OVERHEAD_LIMIT = 0.05  # --smoke gate: enabled/disabled - 1 must stay below
 
@@ -211,6 +212,7 @@ def obs_rows(smoke: bool = False) -> list[tuple]:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true", help="small sizes + hard pass/fail gate")
     args = ap.parse_args()

@@ -42,6 +42,7 @@ import numpy as np
 from repro import rsp
 from repro.core.estimators import block_histogram, block_moments
 from repro.kernels.block_sketch import block_sketch
+from repro.runtime import use_compile_cache
 
 
 def _build(num_blocks: int, block_records: int, features: int, *, shift: float = 5.0):
@@ -156,6 +157,7 @@ def query_rows(smoke: bool = False) -> list[tuple]:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true", help="small sizes + hard pass/fail gate")
     args = ap.parse_args()

@@ -15,6 +15,7 @@ import sys
 import traceback
 
 from benchmarks.artifact import write_artifact
+from repro.runtime import use_compile_cache
 
 
 def _roofline_rows() -> list[tuple[str, float, str]]:
@@ -80,6 +81,7 @@ def _register_suites():
 
 
 def main() -> None:
+    use_compile_cache()
     _register_suites()
     which = sys.argv[1:] or ["paper", "kernels", "roofline"]
     for w in which:

@@ -52,6 +52,7 @@ import numpy as np
 from benchmarks.artifact import write_artifact
 from repro import rsp
 from repro.rsp.query import derive_seed
+from repro.runtime import use_compile_cache
 from repro.serve import AdmissionRejected
 
 # latency slack added on top of a query's deadline budget before the p99
@@ -309,6 +310,7 @@ def _verdict(gates: dict) -> list[str]:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument(
         "--smoke", action="store_true", help="CI sizes + hard pass/fail gate"

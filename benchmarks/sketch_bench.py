@@ -43,6 +43,7 @@ from repro.core.types import RSPSpec
 from repro.rsp.dataset import RSPDataset
 from repro.rsp.sketch import kll_rank_error_bound, merge_suites
 from repro.rsp.summaries import summarize_blocks
+from repro.runtime import use_compile_cache
 
 PREDICATE = "c0 > 1.0"
 QUANTILES = {"p50": 0.5, "p95": 0.95}
@@ -171,6 +172,7 @@ def sketch_rows(smoke: bool = False) -> list[tuple]:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes + hard pass/fail gate")

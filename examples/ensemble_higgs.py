@@ -19,9 +19,11 @@ import numpy as np
 
 from repro import rsp
 from repro.data import make_higgs_like
+from repro.runtime import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--records", type=int, default=100_000)
     ap.add_argument("--blocks", type=int, default=50)

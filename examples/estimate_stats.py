@@ -10,9 +10,11 @@ import numpy as np
 from repro import rsp
 from repro.core import block_histogram, quantile_from_histogram
 from repro.data import make_higgs_like
+from repro.runtime import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     N, K = 200_000, 100
     x, y = make_higgs_like(N, seed=4)
     data = np.concatenate([x, y[:, None].astype(np.float32)], axis=1)

@@ -13,11 +13,15 @@ import numpy as np
 
 from repro import rsp
 from repro.data import make_nonrandom_higgs_like
+from repro.runtime import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     # 1. a "big" data set, stored in the worst possible order (sorted by class)
-    N, K = 40_000, 40
+    # delta = N / K^2 = 24 records per slice: a whole number of the TPU's
+    # 8-row sublane tiles, so on a TPU host "auto" can pick the Pallas kernel
+    N, K = 38_400, 40
     x, y = make_nonrandom_higgs_like(N + 8_000, seed=0, class_sep=1.5)
     xe, ye = x[N:], y[N:].astype(np.int32)
     data = np.concatenate([x[:N], y[:N, None].astype(np.float32)], axis=1)

@@ -22,6 +22,7 @@ from repro.data.synthetic import make_token_corpus
 from repro.models import api
 from repro.models.common import init_params
 from repro.optim import AdamWConfig
+from repro.runtime import use_compile_cache
 from repro.serve.engine import EnsembleServer, ServeConfig, Server
 from repro.train import TrainConfig, init_state, make_train_step
 
@@ -39,6 +40,7 @@ def train_on_blocks(cfg, block_tokens, steps=30, seed=0):
 
 
 def main():
+    use_compile_cache()
     cfg = dataclasses.replace(
         ARCHS["llama3.2-1b"],
         num_layers=2, d_model=128, num_heads=4, num_kv_heads=2, d_ff=512,

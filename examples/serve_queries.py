@@ -17,6 +17,7 @@ import tempfile
 import numpy as np
 
 from repro import rsp
+from repro.runtime import use_compile_cache
 from repro.serve import AdmissionRejected
 
 
@@ -27,6 +28,7 @@ def show(tag, ticket, res):
 
 
 def main():
+    use_compile_cache()
     rng = np.random.default_rng(0)
     data = rng.normal(5.0, 1.0, size=(64 * 1024, 4)).astype(np.float32)
 

@@ -24,6 +24,7 @@ from repro import rsp
 from repro.configs import ARCHS
 from repro.data.synthetic import make_token_corpus
 from repro.optim import AdamWConfig
+from repro.runtime import use_compile_cache
 from repro.train import TrainConfig, Trainer
 
 PRESETS = {
@@ -35,6 +36,7 @@ PRESETS = {
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=sorted(PRESETS), default="cpu-small")
     ap.add_argument("--steps", type=int, default=60)

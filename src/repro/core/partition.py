@@ -26,11 +26,6 @@ import numpy as np
 
 from repro.core.types import RSPSpec
 
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # jax < 0.6: shard_map still lives in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 Array = jax.Array
 
 
@@ -182,7 +177,7 @@ def distributed_rsp_partition(
         sub = jax.lax.all_to_all(sub[None], axis, split_axis=1, concat_axis=0)[:, 0]
         return sub.reshape(N // D, *tail)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(in_spec, jax.sharding.PartitionSpec()),

@@ -20,6 +20,10 @@ module replaces the constants with a tiny measured search:
   selection (they would otherwise "lose" to numpy by 100x for reasons that
   vanish on real hardware).  If every candidate is excluded the pinned
   default wins and the record says so.
+* **A compiled kernel that fails is an error, not a slow candidate.**  On
+  a TPU an exception from a Pallas candidate propagates: a kernel the
+  chip's compiler refuses must not turn silently into a numpy run.  Other
+  candidates that raise are disqualified and recorded as ``(error)``.
 * ``REPRO_AUTOTUNE=off`` (or ``0`` / ``false``) disables measurement
   everywhere: ``choose`` returns the pinned default immediately and
   touches no files.  CI and the tier-1 tests run in this mode, so test
@@ -146,6 +150,13 @@ class Autotuner:
             except OSError:
                 pass
 
+    def records(self) -> dict[str, dict]:
+        """Every winner record (``kernel|key|device`` -> record), measured
+        in this process or loaded from disk."""
+        with self._lock:
+            self._load()
+            return dict(self._mem)
+
     def lookup(self, kernel: str, key: str) -> Candidate | None:
         """The cached winner for ``(kernel, key, device)``, or None."""
         with self._lock:
@@ -170,7 +181,8 @@ class Autotuner:
         With tuning disabled returns ``default`` untouched.  Otherwise the
         cached winner is returned if present; else every non-``interpreted``
         candidate is timed ``repeats`` times via ``measure`` (which returns
-        seconds for one run; exceptions disqualify the candidate), the
+        seconds for one run; exceptions disqualify the candidate, except a
+        compiled Pallas candidate's on a TPU, which propagates), the
         best-of-N fastest wins, and the winner persists to
         :func:`cache_path`.  If no candidate is measurable the ``default``
         wins and the record notes the fallback.
@@ -196,6 +208,8 @@ class Autotuner:
                 try:
                     t = min(measure(c) for _ in range(max(1, repeats)))
                 except Exception:
+                    if c.impl == "pallas" and _device() == "tpu":
+                        raise
                     excluded.append(f"{c.label} (error)")
                     continue
                 measured[c.label] = t * 1e6

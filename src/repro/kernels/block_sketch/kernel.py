@@ -86,7 +86,7 @@ def block_sketch_pallas(
     *,
     bins: int,
     tile_rows: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Run the fused sketch kernel; returns ``(stats [5, F], hist [F, bins])``.
 

@@ -8,8 +8,9 @@ bins, moving a downstream quantile by at most one bin width):
 * ``impl="ref"``    -- plain numpy (float64), the oracle.
 * ``impl="jax"``    -- one jit'd fused pass (scatter-add histogram); vmap'd
   batch variant for stacked blocks.
-* ``impl="pallas"`` -- the tiled TPU kernel (interpret=True off-TPU), moments
-  folded Chan-style across row tiles in VMEM.
+* ``impl="pallas"`` -- the tiled TPU kernel (compiled on a TPU, interpreted
+  elsewhere: :func:`repro.runtime.interpret_mode`), moments folded
+  Chan-style across row tiles in VMEM.
 
 ``impl="auto"`` consults the shared measured autotuner
 (:mod:`repro.kernels.autotune`): the first call at a shape benchmarks the
@@ -33,6 +34,7 @@ from repro.kernels import autotune
 from repro.kernels.autotune import Candidate
 from repro.kernels.block_sketch.kernel import block_sketch_pallas
 from repro.kernels.block_sketch.ref import BlockSketch, _grid, block_sketch_ref
+from repro.runtime import count_kernel_run, interpret_mode
 
 IMPLS = ("auto", "ref", "jax", "pallas")
 
@@ -64,12 +66,16 @@ def batched_block_sketch(blocks: jax.Array, lo: jax.Array, inv_width: jax.Array,
     return jax.vmap(lambda b: _sketch_jax(b, lo, inv_width, bins=bins))(blocks)
 
 
+# jitted so the kernel is traced and compiled once per shape, not per call
+_sketch_pallas = jax.jit(block_sketch_pallas, static_argnames=("bins", "tile_rows", "interpret"))
+
+
 def _inv_width(lo: np.ndarray, hi: np.ndarray, bins: int) -> np.ndarray:
     width = (hi - lo) / max(bins, 1)
     return np.where(width > 0, 1.0 / np.where(width > 0, width, 1.0), 0.0)
 
 
-def _auto_config(block, *, bins, lo, hi, interpret) -> Candidate:
+def _auto_config(block, *, bins, lo, hi) -> Candidate:
     """Tuner-backed (impl, tile) choice for this block's shape bucket."""
     dev = jax.default_backend()
     default = Candidate("ref") if dev == "cpu" else Candidate("jax")
@@ -78,16 +84,13 @@ def _auto_config(block, *, bins, lo, hi, interpret) -> Candidate:
     f = int(np.prod(shape[1:])) if len(shape) > 1 else 1
     cands = [Candidate("ref"), Candidate("jax")]
     if bins >= 1:
-        on_tpu = dev == "tpu"
         # off-TPU the Pallas kernel runs interpreted; flagged so the tuner
         # never crowns a config from interpret-mode timings
-        cands += [Candidate("pallas", t, interpreted=not on_tpu) for t in PALLAS_TILES]
+        interpreted = interpret_mode()
+        cands += [Candidate("pallas", t, interpreted=interpreted) for t in PALLAS_TILES]
 
     def measure(c: Candidate) -> float:
-        run = lambda: block_sketch(  # noqa: E731
-            block, bins=bins, lo=lo, hi=hi, impl=c.impl,
-            tile_rows=c.tile_rows, interpret=interpret,
-        )
+        run = lambda: _sketch(block, bins, lo, hi, c.impl, c.tile_rows)  # noqa: E731
         run()  # warm (jit compile / first-touch) outside the timer
         t0 = time.perf_counter()
         run()
@@ -105,7 +108,6 @@ def block_sketch(
     hi=1.0,
     impl: str = "auto",
     tile_rows: int | None = None,
-    interpret: bool = True,
 ) -> BlockSketch:
     """Fused sketch of one block (any shape ``[n, ...]``; features flatten).
 
@@ -118,12 +120,18 @@ def block_sketch(
     if impl not in IMPLS:
         raise ValueError(f"unknown impl {impl!r} (one of {IMPLS})")
     if impl == "auto":
-        cfg = _auto_config(block, bins=bins, lo=lo, hi=hi, interpret=interpret)
+        cfg = _auto_config(block, bins=bins, lo=lo, hi=hi)
         impl = cfg.impl
         if tile_rows is None:
             tile_rows = cfg.tile_rows
-    if tile_rows is None:
+    if impl == "pallas" and tile_rows is None:
         tile_rows = DEFAULT_TILE
+    count_kernel_run("block_sketch", impl, tile_rows if impl == "pallas" else None)
+    return _sketch(block, bins, lo, hi, impl, tile_rows)
+
+
+def _sketch(block, bins, lo, hi, impl, tile_rows) -> BlockSketch:
+    """Run one concrete impl (the dispatcher and the tuner's timer)."""
     if impl == "ref":
         return block_sketch_ref(block, bins=bins, lo=lo, hi=hi)
     x = np.asarray(block, dtype=np.float32).reshape(np.shape(block)[0], -1)
@@ -131,13 +139,13 @@ def block_sketch(
     if impl == "pallas":
         if bins < 1:
             raise ValueError("impl='pallas' needs bins >= 1")
-        stats, hist = block_sketch_pallas(
+        stats, hist = _sketch_pallas(
             jnp.asarray(x),
             jnp.asarray(glo),
             jnp.asarray(_inv_width(glo, ghi, bins)),
             bins=bins,
             tile_rows=tile_rows,
-            interpret=interpret,
+            interpret=interpret_mode(),
         )
         stats = np.asarray(stats, dtype=np.float64)
         return BlockSketch(

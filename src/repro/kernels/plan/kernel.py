@@ -22,8 +22,9 @@ Outputs (2D, TPU-friendly):
 * ``stats [G * 5, Fp]`` -- per group g, rows ``5g..5g+4`` are (count,
   mean, M2, min, max) over the selected rows of that group;
 * ``hist  [G * Fp, B]`` -- per-group per-feature bin counts;
-* ``nsel  [1, 1]``      -- total selected rows (all groups, including rows
-  whose group label falls outside ``[0, G)``).
+* ``nsel  [1, Fp]``     -- total selected rows (all groups, including rows
+  whose group label falls outside ``[0, G)``), broadcast along the row:
+  Mosaic stores vectors to VMEM, not scalars.
 """
 
 from __future__ import annotations
@@ -95,7 +96,7 @@ def _plan_kernel(
 
     @pl.when(i == 0)
     def _init():
-        nsel_ref[0, 0] = nsel_t
+        nsel_ref[0, :] = jnp.full((fp,), nsel_t, jnp.float32)
         for g, (cnt, mean_t, m2_t, min_t, max_t, hist_t) in enumerate(groups):
             stats_ref[5 * g + 0, :] = jnp.full((fp,), cnt, jnp.float32)
             stats_ref[5 * g + 1, :] = mean_t
@@ -106,7 +107,7 @@ def _plan_kernel(
 
     @pl.when(i > 0)
     def _fold():
-        nsel_ref[0, 0] = nsel_ref[0, 0] + nsel_t
+        nsel_ref[0, :] = nsel_ref[0, :] + nsel_t
         for g, (cnt, mean_t, m2_t, min_t, max_t, hist_t) in enumerate(groups):
             # shared Chan combine (repro.core.moments), traced with xp=jnp
             n, mean, m2 = chan_merge(
@@ -134,10 +135,10 @@ def plan_sketch_pallas(
     plan: QueryPlan,
     bins: int,
     tile_rows: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Run the fused plan kernel; returns ``(stats [G*5, Fp],
-    hist [G*Fp, bins], nsel [1, 1])``.  ``n`` need not divide
+    hist [G*Fp, bins], nsel [1, Fp])``.  ``n`` need not divide
     ``tile_rows``; padded rows are masked like failing predicate rows."""
     if x.ndim != 2:
         raise ValueError(f"block must be [n, F], got shape {x.shape}")
@@ -180,12 +181,12 @@ def plan_sketch_pallas(
         out_specs=[
             pl.BlockSpec((5 * g, fp), lambda i: (0, 0)),
             pl.BlockSpec((fp * g, bins), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, fp), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((5 * g, fp), jnp.float32),
             jax.ShapeDtypeStruct((fp * g, bins), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, fp), jnp.float32),
         ],
         interpret=interpret,
     )(*inputs)

@@ -15,7 +15,8 @@ standing bin-edge caveat):
   histogram); the accelerator path.
 * ``impl="pallas"`` -- the row-tiled TPU kernel (``plan.kernel``): rows
   failing a predicate are masked inside the same VMEM pass as the Chan
-  moment fold and histogram scatter.
+  moment fold and histogram scatter.  Compiled on a TPU, interpreted
+  elsewhere (:func:`repro.runtime.interpret_mode`).
 
 Kernels are **compiled per plan**: :func:`compile_plan` closes over the
 plan's predicates/columns/groups as constants and memoizes on
@@ -42,11 +43,14 @@ from repro.kernels.block_sketch.ops import _inv_width
 from repro.kernels.block_sketch.ref import BlockSketch, _grid
 from repro.kernels.plan.plan import QueryPlan
 from repro.kernels.plan.ref import PlanResult, plan_sketch_ref
+from repro.runtime import count_kernel_run, interpret_mode
 
 IMPLS = ("auto", "ref", "np", "jax", "pallas")
 
 NP_TILES = (8192, 16384, 32768, 65536)
-PALLAS_TILES = (128, 256, 512, 1024)
+# 1024-row tiles of a filtered or grouped plan overflow v5e's scoped VMEM
+# at F=29, bins=64 (the 3-D histogram one-hot is [T, F, bins])
+PALLAS_TILES = (128, 256, 512)
 DEFAULT_NP_TILE = 16384  # the pinned REPRO_AUTOTUNE=off choice on CPU
 
 _CACHE: dict[tuple, Callable] = {}
@@ -273,6 +277,9 @@ def _build_jax(plan, f, bins):
 
 
 def _build_pallas(plan, f, bins, tile_rows, interpret):
+    import functools
+
+    import jax
     import jax.numpy as jnp
 
     from repro.kernels.plan.kernel import plan_sketch_pallas
@@ -280,16 +287,18 @@ def _build_pallas(plan, f, bins, tile_rows, interpret):
     cols = plan.resolve_columns(f)
     fp = len(cols)
     G = plan.groups
+    # one jitted callable per cache entry: an eager pallas_call would
+    # re-trace the kernel, and recompile it, on every block
+    fused = jax.jit(
+        functools.partial(
+            plan_sketch_pallas, plan=plan, bins=bins, tile_rows=tile_rows,
+            interpret=interpret,
+        )
+    )
 
     def run(x, glo, ghi):
-        stats, hist, nsel = plan_sketch_pallas(
-            jnp.asarray(x),
-            jnp.asarray(glo),
-            jnp.asarray(_inv_width(glo, ghi, bins)),
-            plan=plan,
-            bins=bins,
-            tile_rows=tile_rows,
-            interpret=interpret,
+        stats, hist, nsel = fused(
+            jnp.asarray(x), jnp.asarray(glo), jnp.asarray(_inv_width(glo, ghi, bins))
         )
         stats = np.asarray(stats, np.float64).reshape(G, 5, fp)
         hist = np.rint(np.asarray(hist, np.float64)).astype(np.int64)
@@ -302,6 +311,13 @@ def _build_pallas(plan, f, bins, tile_rows, interpret):
     return run
 
 
+def _tile(impl: str, tile_rows: int | None) -> int | None:
+    """The tile a tiled impl runs with when none is pinned."""
+    if impl in ("np", "pallas") and tile_rows is None:
+        return DEFAULT_NP_TILE if impl == "np" else PALLAS_TILES[0]
+    return tile_rows
+
+
 def compile_plan(
     plan: QueryPlan,
     *,
@@ -309,17 +325,16 @@ def compile_plan(
     bins: int = 0,
     impl: str = "np",
     tile_rows: int | None = None,
-    interpret: bool = True,
 ) -> Callable:
     """The compiled executor ``run(x32, glo, ghi) -> PlanResult`` for
     ``plan`` at this shape, memoized on ``(plan.key(), features, bins,
-    impl, tile)`` -- the plan-keyed compile cache."""
+    impl, tile, interpret mode)`` -- the plan-keyed compile cache."""
     global _HITS, _MISSES
     if impl not in IMPLS or impl == "auto":
         raise ValueError(f"compile_plan impl must be concrete, got {impl!r}")
-    if impl in ("np", "pallas") and tile_rows is None:
-        tile_rows = DEFAULT_NP_TILE if impl == "np" else PALLAS_TILES[0]
-    key = (plan.key(), int(num_features), int(bins), impl, tile_rows, bool(interpret))
+    tile_rows = _tile(impl, tile_rows)
+    interpret = impl == "pallas" and interpret_mode()
+    key = (plan.key(), int(num_features), int(bins), impl, tile_rows, interpret)
     telemetry = obs.enabled()
     with _CACHE_LOCK:
         fn = _CACHE.get(key)
@@ -364,12 +379,11 @@ def _default_candidate() -> Candidate:
     return Candidate("jax")
 
 
-def _auto_config(plan, x, glo, ghi, *, bins, interpret) -> Candidate:
+def _auto_config(plan, x, glo, ghi, *, bins) -> Candidate:
     import jax
 
     n, f = x.shape
     dev = jax.default_backend()
-    on_tpu = dev == "tpu"
     cands = [Candidate("np", t) for t in NP_TILES]
     cands.append(Candidate("ref"))
     if dev != "cpu":
@@ -377,15 +391,15 @@ def _auto_config(plan, x, glo, ghi, *, bins, interpret) -> Candidate:
     if bins >= 1:
         # off-TPU these run the Pallas interpreter; flagged so the tuner
         # never crowns a config from interpret-mode timings
+        interpreted = interpret_mode()
         cands += [
-            Candidate("pallas", t, interpreted=not on_tpu) for t in PALLAS_TILES
+            Candidate("pallas", t, interpreted=interpreted) for t in PALLAS_TILES
         ]
     key = autotune.shape_key(n, f) + f"|g{plan.groups}p{len(plan.predicates)}c{len(plan.resolve_columns(f))}b{bins}"
 
     def measure(c: Candidate) -> float:
         fn = compile_plan(
-            plan, num_features=f, bins=bins, impl=c.impl, tile_rows=c.tile_rows,
-            interpret=interpret and not on_tpu,
+            plan, num_features=f, bins=bins, impl=c.impl, tile_rows=c.tile_rows
         )
         fn(x, glo, ghi)  # warm (jit compile / first-touch) outside the timer
         t0 = time.perf_counter()
@@ -406,7 +420,6 @@ def plan_sketch(
     hi=1.0,
     impl: str = "auto",
     tile_rows: int | None = None,
-    interpret: bool = True,
 ) -> PlanResult:
     """Execute ``plan`` over one block (any ``[n, ...]`` shape; features
     flatten) in a single fused pass.
@@ -428,14 +441,13 @@ def plan_sketch(
     if impl == "pallas" and bins == 0:
         impl = "jax"
     if impl == "auto":
-        cfg = _auto_config(plan, x, glo, ghi, bins=bins, interpret=interpret)
+        cfg = _auto_config(plan, x, glo, ghi, bins=bins)
         impl = cfg.impl
         if tile_rows is None:
             tile_rows = cfg.tile_rows
         if impl == "pallas" and bins == 0:
             impl = "jax"
-    fn = compile_plan(
-        plan, num_features=f, bins=bins, impl=impl, tile_rows=tile_rows,
-        interpret=interpret,
-    )
+    tile_rows = _tile(impl, tile_rows)
+    fn = compile_plan(plan, num_features=f, bins=bins, impl=impl, tile_rows=tile_rows)
+    count_kernel_run("plan", impl, tile_rows if impl in ("np", "pallas") else None)
     return fn(x, glo, ghi)

@@ -31,13 +31,17 @@ from jax.experimental.pallas import tpu as pltpu
 def _shuffle_kernel(tile_perm_ref, intra_ref, x_ref, o_ref):
     del tile_perm_ref  # consumed by the index_map
     tile = x_ref[...]                       # [T, D] (gathered tile)
-    perm = intra_ref[0]                     # [T] int32
+    perm = intra_ref[...]                   # [T, 1] int32
     T = tile.shape[0]
     # one-hot permutation matrix on the MXU: onehot[r, c] = (c == perm[r])
     cols = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    onehot = (cols == perm[:, None]).astype(tile.dtype)
+    onehot = (cols == perm).astype(tile.dtype)
+    # HIGHEST keeps the f32 contraction exact: each output row is 1.0 times
+    # one input row, so a reduced-precision pass would round the records
     o_ref[...] = jax.lax.dot_general(
-        onehot, tile, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, tile, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     ).astype(o_ref.dtype)
 
 
@@ -47,7 +51,7 @@ def rsp_shuffle_pallas(
     intra_perm: jax.Array,  # [num_tiles, T] int32 -- row perm within each tile
     *,
     tile_rows: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     R, D = x.shape
     if R % tile_rows:
@@ -58,7 +62,9 @@ def rsp_shuffle_pallas(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tile_rows), lambda i, tp: (i, 0)),
+            # [n_tiles, T, 1]: the block's last two dims are the array's own,
+            # which the TPU tiling rule accepts for any T
+            pl.BlockSpec((None, tile_rows, 1), lambda i, tp: (i, 0, 0)),
             pl.BlockSpec((tile_rows, D), lambda i, tp: (tp[i], 0)),
         ],
         out_specs=pl.BlockSpec((tile_rows, D), lambda i, tp: (i, 0)),
@@ -69,4 +75,8 @@ def rsp_shuffle_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
         interpret=interpret,
-    )(tile_perm.astype(jnp.int32), intra_perm.astype(jnp.int32), x)
+    )(
+        tile_perm.astype(jnp.int32),
+        intra_perm.astype(jnp.int32).reshape(n_tiles, tile_rows, 1),
+        x,
+    )

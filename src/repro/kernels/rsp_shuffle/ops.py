@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.kernels import autotune
 from repro.kernels.autotune import Candidate
 from repro.kernels.rsp_shuffle.kernel import rsp_shuffle_pallas
+from repro.runtime import count_kernel_run, interpret_mode
 
 SHUFFLE_TILES = (64, 128, 256, 512, 1024)
 DEFAULT_SHUFFLE_TILE = 256
@@ -42,7 +43,7 @@ def _randomize(x: jax.Array, key: jax.Array, *, tile_rows: int, interpret: bool)
     return rsp_shuffle_pallas(x, tile_perm, intra, tile_rows=tile_rows, interpret=interpret)
 
 
-def _auto_tile(x: jax.Array, *, interpret: bool) -> int:
+def _auto_tile(x: jax.Array) -> int:
     """Tuner-backed shuffle tile: fastest divisor of ``R`` at this shape.
 
     Off-TPU the kernel runs in Pallas interpret mode, so every candidate is
@@ -55,8 +56,8 @@ def _auto_tile(x: jax.Array, *, interpret: bool) -> int:
             f"no tile in {SHUFFLE_TILES} divides R={R}; pass tile_rows explicitly"
         )
     default_tile = DEFAULT_SHUFFLE_TILE if R % DEFAULT_SHUFFLE_TILE == 0 else valid[-1]
-    on_tpu = jax.default_backend() == "tpu"
-    cands = [Candidate("pallas", t, interpreted=not on_tpu) for t in valid]
+    interpret = interpret_mode()
+    cands = [Candidate("pallas", t, interpreted=interpret) for t in valid]
 
     def measure(c: Candidate) -> float:
         key = jax.random.PRNGKey(0)
@@ -73,7 +74,7 @@ def _auto_tile(x: jax.Array, *, interpret: bool) -> int:
 
 
 def rsp_randomize_block(
-    x: jax.Array, key: jax.Array, *, tile_rows: int | None = None, interpret: bool = True
+    x: jax.Array, key: jax.Array, *, tile_rows: int | None = None
 ) -> jax.Array:
     """Randomize one original block [R, D] on-device (hierarchical shuffle).
 
@@ -81,17 +82,16 @@ def rsp_randomize_block(
     tile is honored verbatim (and is part of the shuffle's definition --
     two calls with different tiles produce different permutations)."""
     if tile_rows is None:
-        tile_rows = _auto_tile(x, interpret=interpret)
-    return _randomize(x, key, tile_rows=tile_rows, interpret=interpret)
+        tile_rows = _auto_tile(x)
+    count_kernel_run("rsp_shuffle", "pallas", tile_rows)
+    return _randomize(x, key, tile_rows=tile_rows, interpret=interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
+_shuffle = jax.jit(rsp_shuffle_pallas, static_argnames=("tile_rows", "interpret"))
+
+
 def rsp_shuffle(
-    x: jax.Array,
-    tile_perm: jax.Array,
-    intra_perm: jax.Array,
-    *,
-    tile_rows: int,
-    interpret: bool = True,
+    x: jax.Array, tile_perm: jax.Array, intra_perm: jax.Array, *, tile_rows: int
 ) -> jax.Array:
-    return rsp_shuffle_pallas(x, tile_perm, intra_perm, tile_rows=tile_rows, interpret=interpret)
+    """Apply a given hierarchical permutation (``ref.py`` is the oracle)."""
+    return _shuffle(x, tile_perm, intra_perm, tile_rows=tile_rows, interpret=interpret_mode())

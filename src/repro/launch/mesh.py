@@ -19,10 +19,7 @@ import numpy as np
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return make_host_mesh(shape, axes)  # jax < 0.5: no AxisType, plain Mesh
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
@@ -32,9 +29,25 @@ def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.shardin
     return jax.sharding.Mesh(devs, axes)
 
 
-# v5e hardware constants used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_LINK_BW = 50e9              # bytes/s per link (per chip, effective)
-CHIPS_PER_POD = 256
-HBM_BYTES = 16 * 1024**3        # v5e: 16 GiB per chip
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM
+# at 819 GB/s, 1,600 Gbit/s of interconnect over four links).
+PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "hbm_bw": 819e9,            # bytes/s
+        "ici_link_bw": 50e9,        # bytes/s per link
+        "hbm_bytes": 16 * 1024**3,
+    },
+}
+TARGET_DEVICE_KIND = "TPU v5 lite"  # the chip the production mesh describes
+
+
+def peaks(device_kind: str) -> dict[str, float]:
+    """The peak table row for ``device_kind``; an unknown device is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: {sorted(PEAKS)})"
+        ) from None

@@ -326,12 +326,13 @@ _WIRE_FACTOR = {
 def roofline_terms(analysis: dict, *, chips: int) -> dict:
     """Per-chip seconds for each roofline term.  ``analysis`` comes from the
     per-device (partitioned) module, so flops/bytes are already per chip."""
-    t_compute = analysis["flops"] / mesh_lib.PEAK_FLOPS_BF16
-    t_memory = analysis["bytes"] / mesh_lib.HBM_BW
+    peak = mesh_lib.peaks(mesh_lib.TARGET_DEVICE_KIND)
+    t_compute = analysis["flops"] / peak["flops_bf16"]
+    t_memory = analysis["bytes"] / peak["hbm_bw"]
     wire = 0.0
     for kind, b in analysis.get("collectives", {}).items():
         wire += b["bytes"] * _WIRE_FACTOR.get(kind, 1.0)
-    t_coll = wire / mesh_lib.ICI_LINK_BW
+    t_coll = wire / peak["ici_link_bw"]
     dominant = max(
         ("compute", t_compute), ("memory", t_memory), ("collective", t_coll),
         key=lambda kv: kv[1],
@@ -359,7 +360,8 @@ def summarize_cell(result: dict, cfg, cell) -> dict:
         step_time_s=max(terms["t_compute_s"], terms["t_memory_s"], terms["t_collective_s"]),
     )
     terms["roofline_fraction"] = (
-        (mf / chips / mesh_lib.PEAK_FLOPS_BF16) / terms["step_time_s"]
+        (mf / chips / mesh_lib.peaks(mesh_lib.TARGET_DEVICE_KIND)["flops_bf16"])
+        / terms["step_time_s"]
         if terms["step_time_s"] > 0
         else float("nan")
     )

@@ -16,7 +16,9 @@ says whether it can serve a given request:
                  requires a mesh with P = K = mesh size.
     pallas    -- the ``rsp_shuffle`` TPU kernel: hierarchical tile shuffle
                  per original block with the delta-slice dealing expressed
-                 as DMA scheduling; requires 2-D floating-point data.
+                 as DMA scheduling; requires 2-D floating-point data and,
+                 where it compiles (a TPU), a delta that fills whole
+                 sublane tiles.
 
 ``backend="auto"`` selects shard_map when a mesh is supplied, Pallas when
 the kernel's shape constraints hold *and* a TPU is attached (off-TPU the
@@ -44,6 +46,7 @@ from repro.core.partition import (
 from repro.core.registry import RSPStore
 from repro.core.types import RSPSpec
 from repro.kernels.rsp_shuffle.ops import rsp_randomize_block
+from repro.runtime import interpret_mode
 from repro.rsp.ingest import (
     is_stream_source,
     maybe_chunk_source,
@@ -268,8 +271,12 @@ def _supports_shard_map(req: PartitionRequest) -> str | None:
 
 
 def _run_shard_map(req: PartitionRequest) -> np.ndarray:
+    # place each original block straight onto its device: jnp.asarray would
+    # first copy the whole corpus to device 0
+    rank = np.ndim(req.data)
+    spec = jax.sharding.PartitionSpec(req.mesh_axis, *(None,) * (rank - 1))
     out = distributed_rsp_partition(
-        jnp.asarray(req.data),
+        jax.device_put(req.data, jax.sharding.NamedSharding(req.mesh, spec)),
         jax.random.PRNGKey(req.spec.seed),
         req.mesh,
         axis=req.mesh_axis,
@@ -290,6 +297,14 @@ def _supports_pallas(req: PartitionRequest) -> str | None:
         return f"kernel shuffles via an MXU matmul and needs a float dtype, got {dtype}"
     if not req.permute_assignment:
         return "sub-block assignment permutation is intrinsic to the tile dealing"
+    # compiled, the kernel's tile is delta rows of the block, and Mosaic
+    # tiles rows in sublanes of 8 32-bit words (16 for 16-bit dtypes)
+    sublanes = 32 // min(np.dtype(dtype).itemsize, 4)
+    if not interpret_mode() and req.spec.slice_size % sublanes:
+        return (
+            f"delta={req.spec.slice_size} rows is not a multiple of {sublanes},"
+            " the sublane tiling the TPU compiler requires"
+        )
     return None
 
 
@@ -297,7 +312,7 @@ def _auto_pallas(req: PartitionRequest) -> str | None:
     # off-TPU the kernel runs in interpret mode, far slower than the numpy
     # path -- don't win auto-selection there (explicit backend="pallas"
     # still works, e.g. for kernel plumbing tests).
-    if jax.default_backend() != "tpu":
+    if interpret_mode():
         return "interpret-mode off-TPU is slower than np (request it explicitly)"
     return None
 
@@ -309,25 +324,23 @@ def _run_pallas(req: PartitionRequest) -> np.ndarray:
     permutation *be* the sub-block dealing: output tile k of block i is the
     (intra-shuffled) sub-block destined for RSP block k.  Lemma 1 applies at
     slice granularity (see kernels.rsp_shuffle.kernel).
+
+    Original blocks stream through the device one at a time, so device
+    memory holds one block, not the corpus and its shuffled copies.
     """
     spec = req.spec
     P, K, delta = spec.num_original_blocks, spec.num_blocks, spec.slice_size
-    x = jnp.asarray(req.data)
-    R, F = spec.original_block_size, x.shape[1]
-    interpret = jax.default_backend() != "tpu"
+    data = np.asarray(req.data)
+    R, F = spec.original_block_size, data.shape[1]
     key = jax.random.PRNGKey(spec.seed)
-    sub = jnp.stack(
-        [
-            rsp_randomize_block(
-                x[i * R : (i + 1) * R],
-                jax.random.fold_in(key, i),
-                tile_rows=delta,
-                interpret=interpret,
-            ).reshape(K, delta, F)
-            for i in range(P)
-        ]
-    )  # [P, K, delta, F]
-    return np.asarray(sub.transpose(1, 0, 2, 3).reshape(K, P * delta, F))
+    out = np.empty((K, P * delta, F), jax.dtypes.canonicalize_dtype(data.dtype))
+    for i in range(P):
+        sub = rsp_randomize_block(
+            jnp.asarray(data[i * R : (i + 1) * R]), jax.random.fold_in(key, i), tile_rows=delta
+        )
+        # tile k of original block i is its sub-block dealt to RSP block k
+        out[:, i * delta : (i + 1) * delta] = np.asarray(sub).reshape(K, delta, F)
+    return out
 
 
 register_backend(

@@ -125,6 +125,23 @@ def test_failing_candidate_is_disqualified(tuner, monkeypatch):
     assert "ref (error)" in rec["excluded"]
 
 
+def test_compiled_pallas_failure_propagates_on_tpu(tuner, monkeypatch):
+    """On a TPU a Pallas candidate that raises is a kernel the chip's compiler
+    refused: the error surfaces instead of the tuner quietly picking numpy."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "on")
+    monkeypatch.setattr(autotune, "_device", lambda: "tpu")
+
+    def measure(c):
+        if c.impl == "pallas":
+            raise RuntimeError("Mosaic refused the kernel")
+        return 1e-3
+
+    cands = [Candidate("np", 8192), Candidate("pallas", 128)]
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        tuner.choose("k", "key", cands, measure, default=DEFAULT)
+    assert not os.path.exists(tuner._file())  # nothing recorded
+
+
 def test_clear_forgets_disk_and_memory(tuner, monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE", "on")
     measure, _ = _times({"np:8192": 1e-3, "np:32768": 2e-3, "ref": 3e-3})

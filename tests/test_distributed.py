@@ -152,10 +152,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # --- compressed psum over a 'pod' axis -----------------------------------
 from repro.distributed.compression import compressed_psum
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax < 0.6: shard_map still lives in experimental
-    from jax.experimental.shard_map import shard_map
 
 mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("pod", "data"))
 x = jax.random.normal(jax.random.PRNGKey(0), (2, 256))
@@ -163,7 +159,7 @@ x = jax.random.normal(jax.random.PRNGKey(0), (2, 256))
 def f(x):
     return compressed_psum(x, "pod")
 
-y = jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None)))(x)
+y = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None)))(x)
 want = jnp.broadcast_to(x.mean(axis=0, keepdims=True), x.shape)
 err = float(jnp.abs(np.asarray(y) - want).max())
 rel = err / float(jnp.abs(want).max())
