@@ -26,6 +26,7 @@ from typing import Iterable
 
 import numpy as np
 
+from repro import obs
 from repro.core.types import BlockDescriptor, RSPSpec
 
 _CHECKSUM_STEP_BYTES = 4 << 20
@@ -90,22 +91,23 @@ class RSPStore:
         os.makedirs(self.root, exist_ok=True)
         descriptors: list[BlockDescriptor] = []
         for k, block in enumerate(blocks):
-            block = np.asarray(block)
-            path = self._block_path(k)
-            # atomic write: deterministic temp name, one replace.  The .npy
-            # suffix stops np.save from appending its own, so the temp file
-            # written is exactly the file renamed.
-            tmp = path + ".tmp.npy"
-            np.save(tmp, block, allow_pickle=False)
-            os.replace(tmp, path)
-            descriptors.append(
-                BlockDescriptor(
-                    block_id=k,
-                    num_records=int(block.shape[0]),
-                    path=os.path.basename(path),
-                    checksum=_checksum(block),
+            with obs.span("store.block", block=k):
+                block = np.asarray(block)
+                path = self._block_path(k)
+                # atomic write: deterministic temp name, one replace.  The .npy
+                # suffix stops np.save from appending its own, so the temp file
+                # written is exactly the file renamed.
+                tmp = path + ".tmp.npy"
+                np.save(tmp, block, allow_pickle=False)
+                os.replace(tmp, path)
+                descriptors.append(
+                    BlockDescriptor(
+                        block_id=k,
+                        num_records=int(block.shape[0]),
+                        path=os.path.basename(path),
+                        checksum=_checksum(block),
+                    )
                 )
-            )
         self._sweep_stale(len(descriptors))
         self._publish_manifest(
             spec, descriptors, summaries=summaries, meta=meta,
@@ -221,9 +223,10 @@ class RSPStore:
             with open(tmp, "w") as f:
                 f.write('{"version": %d, "summaries": [' % int(sketch_schema["version"]))
                 for i, s in enumerate(summaries):
-                    if i:
-                        f.write(",")
-                    json.dump(s.to_dict() if hasattr(s, "to_dict") else s, f)
+                    with obs.span("store.sketch", block=i):
+                        if i:
+                            f.write(",")
+                        json.dump(s.to_dict() if hasattr(s, "to_dict") else s, f)
                 f.write("]}")
             os.replace(tmp, sketches_path)
             manifest["sketches_file"] = self.SKETCHES

@@ -40,7 +40,6 @@ import dataclasses
 import json
 import os
 import threading
-import time
 from typing import Callable, Sequence
 
 _ENV = "REPRO_AUTOTUNE"
@@ -196,7 +195,6 @@ class Autotuner:
             cached = self.lookup(kernel, key)
             if cached is not None:
                 return cached
-            t_tune = time.perf_counter()
             measured: dict[str, float] = {}
             excluded: list[str] = []
             best: Candidate | None = None
@@ -217,19 +215,6 @@ class Autotuner:
                     best, best_t = c, t
             self.measurements += 1
             winner = best if best is not None else default
-            from repro import obs  # deferred: keep this module import-light
-
-            if obs.enabled():
-                reg = obs.get_registry()
-                reg.counter(
-                    "rsp_autotune_runs_total", "tuning measurement runs",
-                    kernel=kernel,
-                ).inc()
-                reg.histogram(
-                    "rsp_autotune_measure_seconds",
-                    "wall time spent timing candidates for one tuning run",
-                    kernel=kernel,
-                ).observe(time.perf_counter() - t_tune)
             rec = {
                 "impl": winner.impl,
                 "tile_rows": winner.tile_rows,

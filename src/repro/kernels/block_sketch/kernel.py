@@ -122,6 +122,7 @@ def block_sketch_pallas(
             jax.ShapeDtypeStruct((5, f), jnp.float32),
             jax.ShapeDtypeStruct((f, bins), jnp.float32),
         ],
+        name="block_sketch",
         interpret=interpret,
     )(
         x.astype(jnp.float32),

@@ -34,7 +34,7 @@ from repro.kernels import autotune
 from repro.kernels.autotune import Candidate
 from repro.kernels.block_sketch.kernel import block_sketch_pallas
 from repro.kernels.block_sketch.ref import BlockSketch, _grid, block_sketch_ref
-from repro.runtime import count_kernel_run, interpret_mode
+from repro.runtime import count_kernel_run, interpret_mode, to_device, to_host
 
 IMPLS = ("auto", "ref", "jax", "pallas")
 
@@ -139,14 +139,14 @@ def _sketch(block, bins, lo, hi, impl, tile_rows) -> BlockSketch:
     if impl == "pallas":
         if bins < 1:
             raise ValueError("impl='pallas' needs bins >= 1")
-        stats, hist = _sketch_pallas(
-            jnp.asarray(x),
+        stats, hist = to_host(_sketch_pallas(
+            to_device(x, "block_sketch"),
             jnp.asarray(glo),
             jnp.asarray(_inv_width(glo, ghi, bins)),
             bins=bins,
             tile_rows=tile_rows,
             interpret=interpret_mode(),
-        )
+        ), "block_sketch")
         stats = np.asarray(stats, dtype=np.float64)
         return BlockSketch(
             count=float(stats[0, 0]),
@@ -154,23 +154,23 @@ def _sketch(block, bins, lo, hi, impl, tile_rows) -> BlockSketch:
             m2=stats[2],
             min=stats[3],
             max=stats[4],
-            hist=np.asarray(np.rint(np.asarray(hist)), dtype=np.int64),
+            hist=np.asarray(np.rint(hist), dtype=np.int64),
             lo=glo,
             hi=ghi,
         )
-    mean, m2, mn, mx, hist = _sketch_jax(
-        jnp.asarray(x),
+    mean, m2, mn, mx, hist = to_host(_sketch_jax(
+        to_device(x, "block_sketch"),
         jnp.asarray(glo, dtype=jnp.float32),
         jnp.asarray(_inv_width(glo, ghi, bins), dtype=jnp.float32),
         bins=bins,
-    )
+    ), "block_sketch")
     return BlockSketch(
         count=float(x.shape[0]),
         mean=np.asarray(mean, dtype=np.float64),
         m2=np.asarray(m2, dtype=np.float64),
         min=np.asarray(mn, dtype=np.float64),
         max=np.asarray(mx, dtype=np.float64),
-        hist=None if bins == 0 else np.asarray(np.rint(np.asarray(hist)), np.int64),
+        hist=None if bins == 0 else np.asarray(np.rint(hist), np.int64),
         lo=None if bins == 0 else glo,
         hi=None if bins == 0 else ghi,
     )

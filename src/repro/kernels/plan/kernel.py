@@ -188,6 +188,7 @@ def plan_sketch_pallas(
             jax.ShapeDtypeStruct((fp * g, bins), jnp.float32),
             jax.ShapeDtypeStruct((1, fp), jnp.float32),
         ],
+        name="plan",
         interpret=interpret,
     )(*inputs)
     return stats, hist, nsel

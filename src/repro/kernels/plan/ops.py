@@ -43,7 +43,7 @@ from repro.kernels.block_sketch.ops import _inv_width
 from repro.kernels.block_sketch.ref import BlockSketch, _grid
 from repro.kernels.plan.plan import QueryPlan
 from repro.kernels.plan.ref import PlanResult, plan_sketch_ref
-from repro.runtime import count_kernel_run, interpret_mode
+from repro.runtime import count_kernel_run, interpret_mode, to_device, to_host
 
 IMPLS = ("auto", "ref", "np", "jax", "pallas")
 
@@ -262,15 +262,15 @@ def _build_jax(plan, f, bins):
 
         lo = np.zeros(fp) if glo is None else glo
         invw = np.zeros(fp) if bins == 0 else _inv_width(glo, ghi, bins)
-        nsel, cnt, mean, m2, mn, mx, hist = fused(
-            jnp.asarray(x), jnp.asarray(lo, jnp.float32), jnp.asarray(invw, jnp.float32)
-        )
+        nsel, cnt, mean, m2, mn, mx, hist = to_host(fused(
+            to_device(x, "plan"), jnp.asarray(lo, jnp.float32), jnp.asarray(invw, jnp.float32)
+        ), "plan")
         return _result(
             plan, fp, bins, glo, ghi, nsel=float(nsel), n=x.shape[0],
             cnt=np.asarray(cnt, np.float64), mean=np.asarray(mean, np.float64),
             m2=np.asarray(m2, np.float64), mn=np.asarray(mn, np.float64),
             mx=np.asarray(mx, np.float64),
-            hist=None if bins == 0 else np.rint(np.asarray(hist)).astype(np.int64),
+            hist=None if bins == 0 else np.rint(hist).astype(np.int64),
         )
 
     return run
@@ -297,13 +297,13 @@ def _build_pallas(plan, f, bins, tile_rows, interpret):
     )
 
     def run(x, glo, ghi):
-        stats, hist, nsel = fused(
-            jnp.asarray(x), jnp.asarray(glo), jnp.asarray(_inv_width(glo, ghi, bins))
-        )
+        stats, hist, nsel = to_host(fused(
+            to_device(x, "plan"), jnp.asarray(glo), jnp.asarray(_inv_width(glo, ghi, bins))
+        ), "plan")
         stats = np.asarray(stats, np.float64).reshape(G, 5, fp)
         hist = np.rint(np.asarray(hist, np.float64)).astype(np.int64)
         return _result(
-            plan, fp, bins, glo, ghi, nsel=float(np.asarray(nsel)[0, 0]),
+            plan, fp, bins, glo, ghi, nsel=float(nsel[0, 0]),
             n=x.shape[0], cnt=stats[:, 0, 0], mean=stats[:, 1], m2=stats[:, 2],
             mn=stats[:, 3], mx=stats[:, 4], hist=hist.reshape(G, fp, bins),
         )

@@ -74,6 +74,7 @@ def rsp_shuffle_pallas(
         _shuffle_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
+        name="rsp_shuffle",
         interpret=interpret,
     )(
         tile_perm.astype(jnp.int32),

@@ -1,19 +1,22 @@
 """``repro.obs`` -- in-process telemetry for the RSP stack.
 
-Three pillars, all zero-dependency and thread-safe:
+Three pillars, all thread-safe:
 
 * **metrics** (:mod:`repro.obs.metrics`) -- counters / gauges /
   exponential-bucket histograms in a label-set registry, exportable as
   JSON and Prometheus text format.
-* **tracing** (:mod:`repro.obs.trace`) -- spans with *explicit* context
-  propagation across executor / scheduler / sweeper threads, exported
-  as Chrome trace-event JSON (open in Perfetto).
+* **tracing** (:mod:`repro.obs.trace`) -- :func:`span` at every layer
+  boundary, written into the JAX profiler's trace whenever a profiler
+  session collects (beside the device ops, on their clock), and, when
+  telemetry is on, into a :class:`Tracer` with *explicit* context
+  propagation across executor / scheduler / sweeper threads, exported as
+  Chrome trace-event JSON (open in Perfetto).
 * **convergence** (:mod:`repro.obs.convergence`) -- per-query
   error-vs-blocks trajectories surfaced on ``QueryResult.trace``.
 
 Telemetry is **off by default**: the hot paths check :func:`enabled`
-(a plain bool read) and skip all metric/span work when off.  Turn it on
-per process::
+(a plain bool read) and skip all metric/span work when off; :func:`span`
+adds one read of the profiler's flag.  Turn it on per process::
 
     from repro import obs
     obs.enable(sample_rate=0.1)        # sample 10% of query traces
@@ -36,7 +39,7 @@ import threading
 
 from .convergence import ConvergenceStep, ConvergenceTrace
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .trace import DROPPED, Span, SpanContext, Tracer
+from .trace import _NOOP, DROPPED, SinkSpan, Span, SpanContext, Tracer, profiling
 
 _lock = threading.Lock()
 _enabled = False
@@ -61,6 +64,20 @@ def disable() -> None:
     global _enabled
     with _lock:
         _enabled = False
+
+
+def span(name: str, *, parent: SpanContext | None = None, **attrs):
+    """Context manager timing one operation that opens and closes on one
+    thread.  While a profiler session collects it is a
+    ``jax.profiler.TraceAnnotation`` named ``name`` with ``attrs`` as its
+    stats, whether or not telemetry is on; with telemetry on it is also
+    recorded in the tracer as a child of ``parent`` when ``parent`` is
+    sampled.  With neither, it is one shared no-op."""
+    profiled = profiling()
+    tracer = _tracer if _enabled and parent is not None else None
+    if not profiled and tracer is None:
+        return _NOOP
+    return SinkSpan(name, parent, attrs, profiled, tracer)
 
 
 def get_registry() -> MetricsRegistry:
@@ -106,6 +123,8 @@ __all__ = [
     "DROPPED",
     "enabled",
     "enable",
+    "span",
+    "profiling",
     "disable",
     "get_registry",
     "get_tracer",

@@ -1,5 +1,14 @@
 """``repro.obs.trace`` -- span-based tracing with explicit context propagation.
 
+Two sinks.  :func:`repro.obs.span` is the one span call the program makes;
+while a JAX profiler session is collecting it writes a
+``jax.profiler.TraceAnnotation`` into the profiler's own trace, on the same
+clock as the device ops, and under :func:`repro.obs.enabled` it also records
+into the :class:`Tracer` below when its parent is sampled.  A span whose life
+crosses threads (the ``query`` root: opened on the submitting thread, ended
+at retire) stays out of the profiler, whose events open and close on one
+thread, and is opened with :meth:`Tracer.start_span`.
+
 The serving stack hops threads constantly: a query is submitted on a
 caller thread, stepped on scheduler workers, fetched on executor pool
 threads, and force-answered by the deadline sweeper.  ``contextvars``
@@ -13,7 +22,7 @@ Usage::
     tracer = obs.get_tracer()
     root = tracer.start_span("query", attrs={"qid": 7})
     ...
-    with tracer.span("engine.fetch", parent=root.ctx, attrs={"block": 3}):
+    with obs.span("engine.fetch", parent=root.ctx, block=3):
         ...         # runs on a worker thread; still parents under `root`
     root.end()
     tracer.export_chrome("trace.json")
@@ -39,6 +48,8 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
+
+from jax.profiler import TraceAnnotation
 
 _ids = threading.local()
 
@@ -102,7 +113,47 @@ class Span:
         self.end()
 
 
+#: The span of no sink: unsampled, and the one object :func:`repro.obs.span`
+#: returns when neither the profiler nor the tracer records.
 _NOOP = Span("", DROPPED, 0, None, None)
+
+#: Whether a profiler session is collecting (a flag read in C++).
+profiling = TraceAnnotation.is_enabled
+
+
+class SinkSpan:
+    """One :func:`repro.obs.span`: a profiler annotation when ``profiled``,
+    and a child of ``parent`` in ``tracer`` when one is given (the tracer
+    drops it when ``parent`` is not sampled).  Opens and closes on one
+    thread."""
+
+    __slots__ = ("_name", "_parent", "_attrs", "_profiled", "_tracer", "_ann", "_span")
+
+    def __init__(self, name: str, parent: SpanContext | None, attrs: dict,
+                 profiled: bool, tracer: "Tracer | None"):
+        self._name = name
+        self._parent = parent
+        self._attrs = attrs
+        self._profiled = profiled
+        self._tracer = tracer
+        self._ann = self._span = None
+
+    def __enter__(self) -> "SinkSpan":
+        if self._profiled:
+            # attributes become the event's stats; its name stays bare
+            self._ann = TraceAnnotation(self._name, **self._attrs)
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._span = self._tracer.start_span(
+                self._name, parent=self._parent, attrs=self._attrs or None
+            )
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._span is not None:
+            self._span.end()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 class Tracer:
@@ -139,11 +190,6 @@ class Tracer:
         tid = _new_id()
         ctx = SpanContext(tid, _new_id(), True)
         return Span(name, ctx, 0, attrs, self)
-
-    def span(self, name: str, *, parent: SpanContext | None = None,
-             attrs: dict | None = None) -> Span:
-        """Alias of :meth:`start_span`, reads better in ``with`` statements."""
-        return self.start_span(name, parent=parent, attrs=attrs)
 
     def _finish(self, span: Span, t1: float) -> None:
         ev = (span.name, span._tid, span._t0, t1,

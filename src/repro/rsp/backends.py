@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.partition import (
     distributed_rsp_partition,
     two_stage_partition_jax,
@@ -46,7 +47,7 @@ from repro.core.partition import (
 from repro.core.registry import RSPStore
 from repro.core.types import RSPSpec
 from repro.kernels.rsp_shuffle.ops import rsp_randomize_block
-from repro.runtime import interpret_mode
+from repro.runtime import interpret_mode, to_device, to_host
 from repro.rsp.ingest import (
     is_stream_source,
     maybe_chunk_source,
@@ -334,12 +335,16 @@ def _run_pallas(req: PartitionRequest) -> np.ndarray:
     R, F = spec.original_block_size, data.shape[1]
     key = jax.random.PRNGKey(spec.seed)
     out = np.empty((K, P * delta, F), jax.dtypes.canonicalize_dtype(data.dtype))
-    for i in range(P):
-        sub = rsp_randomize_block(
-            jnp.asarray(data[i * R : (i + 1) * R]), jax.random.fold_in(key, i), tile_rows=delta
-        )
-        # tile k of original block i is its sub-block dealt to RSP block k
-        out[:, i * delta : (i + 1) * delta] = np.asarray(sub).reshape(K, delta, F)
+    with obs.span("partition.shuffle", blocks=P):
+        for i in range(P):
+            with obs.span("shuffle.block", block=i):
+                sub = rsp_randomize_block(
+                    to_device(data[i * R : (i + 1) * R], "rsp_shuffle"),
+                    jax.random.fold_in(key, i), tile_rows=delta,
+                )
+                # tile k of original block i is its sub-block dealt to RSP block k
+                (sub,) = to_host((sub,), "rsp_shuffle")
+                out[:, i * delta : (i + 1) * delta] = sub.reshape(K, delta, F)
     return out
 
 

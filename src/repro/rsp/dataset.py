@@ -33,6 +33,7 @@ from typing import Any, Callable, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.ensemble import (
     BaseLearner,
     Ensemble,
@@ -201,7 +202,8 @@ class RSPDataset:
             label_column=label_column,
         )
         if summaries:
-            ds._summaries = ds._compute_summaries()
+            with obs.span("partition.sketch", blocks=blocks):
+                ds._summaries = ds._compute_summaries()
         if out is not None:
             ds.save(out)
         return ds
@@ -346,22 +348,23 @@ class RSPDataset:
         """Materialize to ``path`` (blocks + manifest with sketches); chainable."""
         store = RSPStore(path)
         summaries = self.summaries
-        schema = (
-            sketch_schema_descriptor(summaries)
-            if summaries and isinstance(summaries[0], SketchSuite)
-            else None
-        )
-        store.write_partition(
-            self.stacked(),
-            self.spec,
-            summaries=summaries,
-            meta={
-                "backend": self.backend,
-                "num_classes": self.num_classes,
-                "label_column": self.label_column,
-            },
-            sketch_schema=schema,
-        )
+        with obs.span("store.write", blocks=self.num_blocks):
+            schema = (
+                sketch_schema_descriptor(summaries)
+                if summaries and isinstance(summaries[0], SketchSuite)
+                else None
+            )
+            store.write_partition(
+                self.stacked(),
+                self.spec,
+                summaries=summaries,
+                meta={
+                    "backend": self.backend,
+                    "num_classes": self.num_classes,
+                    "label_column": self.label_column,
+                },
+                sketch_schema=schema,
+            )
         self._store = store
         return self
 

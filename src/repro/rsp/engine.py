@@ -39,11 +39,10 @@ measures the three fetch paths.
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import threading
 import time
-from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor, wait
 from typing import Any, Callable, Iterable, Iterator, Protocol, Sequence, runtime_checkable
 
 import numpy as np
@@ -284,9 +283,6 @@ def as_fetcher(source: Any, *, mode: str = "auto") -> BlockFetcher:
     raise TypeError(f"cannot build a BlockFetcher from {type(source).__name__}")
 
 
-_NULL_CM = contextlib.nullcontext()  # stateless; safe to share
-
-
 def _fetcher_kind(fetcher: Any) -> str:
     """Telemetry label for the fetch path: memory | store | mmap | other."""
     if isinstance(fetcher, MemoryFetcher):
@@ -506,14 +502,9 @@ class BlockExecutor:
         trace: SpanContext | None = None,
         submitted: float = 0.0,
     ) -> Any:
-        if not obs.enabled():
-            block = self.fetch(block_id, counter=counter)
-            return fn(block) if fn is not None else block
-        if submitted:
+        if submitted and obs.enabled():
             self._m()["queue_s"].observe(time.perf_counter() - submitted)
-        with obs.get_tracer().span(
-            "engine.fetch", parent=trace, attrs={"block": block_id, "kind": self._kind}
-        ) if trace is not None else _NULL_CM:
+        with obs.span("engine.fetch", parent=trace, block=block_id, kind=self._kind):
             block = self.fetch(block_id, counter=counter)
             return fn(block) if fn is not None else block
 
@@ -548,12 +539,14 @@ class BlockExecutor:
                 submit_one()
             while window:
                 bid, fut = window.popleft()
-                result = fut.result()
+                with obs.span("engine.wait", parent=trace, block=bid):
+                    result = fut.result()
                 submit_one()
                 yield (bid, result) if with_ids else result
         finally:
-            for _, fut in window:
-                fut.cancel()
+            # a fetch already running cannot be cancelled: wait for it, so that
+            # no access of this stream is counted after the stream closes
+            wait([fut for _, fut in window if not fut.cancel()])
 
     def run(self, fn: Callable[[np.ndarray], Any] | None, ids: Sequence[int]) -> list:
         """Materialized :meth:`map_blocks`."""

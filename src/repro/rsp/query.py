@@ -1095,13 +1095,18 @@ class QueryExecutor:
         the executor; ``DistributedQueryExecutor`` overrides only this method
         to gather peer-computed payloads, so both paths fold byte-identical
         payloads through identical code."""
-        executor = self.ds.executor
-        for bid, block in executor.map_blocks(
+        blocks = self.ds.executor.map_blocks(
             None, ids, with_ids=True, counter=self.counter, trace=self.ctx
-        ):
-            yield bid, self._make_payload(
-                block, lo, hi, needs_hist, needs_rows, grouped, need_whole
-            )
+        )
+        try:
+            for bid, block in blocks:
+                with obs.span("query.fold", parent=self.ctx, block=bid):
+                    payload = self._make_payload(
+                        block, lo, hi, needs_hist, needs_rows, grouped, need_whole
+                    )
+                yield bid, payload
+        finally:
+            blocks.close()  # stops the prefetches (see map_blocks)
 
     def stream(self) -> Iterator[QueryResult]:
         """One anytime :class:`QueryResult` per block read."""
@@ -1168,7 +1173,8 @@ class QueryExecutor:
         needs_rows = any(a.kind == "distinct" for a in q.aggregates)
         grouped = any(a.by_label for a in q.aggregates)
         need_whole = any(not a.by_label for a in q.aggregates)
-        states, lo, hi = self._make_states(needs_hist)
+        with obs.span("query.setup", parent=self.ctx):
+            states, lo, hi = self._make_states(needs_hist)
 
         def gen_ids():
             for _ in range(max_blocks):
@@ -1209,7 +1215,8 @@ class QueryExecutor:
                 )
                 if not must_emit:
                     continue
-                results = tuple(s.result() for s in states)
+                with obs.span("query.ci", parent=self.ctx, blocks=b):
+                    results = tuple(s.result() for s in states)
                 errs = [r.rel_err for r in results if r.rel_err is not None]
                 converged = (
                     q.target_rel_err is not None
@@ -1228,6 +1235,10 @@ class QueryExecutor:
                         elapsed_s=time.perf_counter() - self._t0,
                     )
                 )
+                if converged:
+                    # the last answer's stats count every fetch of the query:
+                    # its prefetches end before they are taken
+                    source.close()
                 yield QueryResult(
                     aggregates=results,
                     blocks_read=b,

@@ -23,6 +23,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.estimators import MomentStats
 from repro.core.moments import chan_merge
 from repro.rsp.sketch import (
@@ -124,21 +125,22 @@ def summarize_block(
     same rows on the host."""
     from repro.kernels.block_sketch import block_sketch_ref
 
-    x = np.asarray(block, dtype=np.float64).reshape(block.shape[0], -1)
-    sk = block_sketch_ref(x)
-    suite = SketchSuite.create(
-        block_id,
-        label_column=label_column,
-        num_classes=num_classes,
-        kll_k=kll_k,
-        kmv_k=kmv_k,
-        seed=seed,
-        kinds=kinds,
-    )
-    suite.sketches["moments"] = MomentsSketch.from_block_sketch(sk)
-    for kind, member in suite.sketches.items():
-        if kind != "moments":
-            member.update(x)
+    with obs.span("sketch.block", block=block_id):
+        x = np.asarray(block, dtype=np.float64).reshape(block.shape[0], -1)
+        sk = block_sketch_ref(x)
+        suite = SketchSuite.create(
+            block_id,
+            label_column=label_column,
+            num_classes=num_classes,
+            kll_k=kll_k,
+            kmv_k=kmv_k,
+            seed=seed,
+            kinds=kinds,
+        )
+        suite.sketches["moments"] = MomentsSketch.from_block_sketch(sk)
+        for kind, member in suite.sketches.items():
+            if kind != "moments":
+                member.update(x)
     return suite
 
 

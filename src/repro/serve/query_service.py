@@ -463,24 +463,18 @@ class QueryService:
         if ticket.deadline is not None and time.monotonic() >= ticket.deadline:
             self._finalize(run, outcome="deadline")
             return False
-        span = None
-        if obs.enabled() and run.qe.ctx is not None:
-            span = obs.get_tracer().start_span(
-                "serve.step", parent=run.qe.ctx, attrs={"qid": ticket.id}
-            )
         t0 = time.perf_counter()
-        try:
-            res = next(run.gen)
-        except StopIteration:
-            self._finalize(run, outcome="exhausted")
-            return False
-        except Exception as e:  # noqa: BLE001 -- surface via the ticket
-            self._finalize(run, outcome="failed", error=e)
-            return False
-        finally:
-            self._m_step.observe(time.perf_counter() - t0)
-            if span is not None:
-                span.end()
+        with obs.span("serve.step", parent=run.qe.ctx, qid=ticket.id):
+            try:
+                res = next(run.gen)
+            except StopIteration:
+                self._finalize(run, outcome="exhausted")
+                return False
+            except Exception as e:  # noqa: BLE001 -- surface via the ticket
+                self._finalize(run, outcome="failed", error=e)
+                return False
+            finally:
+                self._m_step.observe(time.perf_counter() - t0)
         run.last = res
         if res.converged or res.from_sketches:
             self._finalize(run, outcome="converged")
@@ -570,20 +564,14 @@ class QueryService:
             run = self._runs.get(ticket.id)
         if run is None:
             return
-        span = None
-        if obs.enabled() and run.qe.ctx is not None:
-            # runs on the sweeper thread (or a result() waiter); parenting
-            # under the query's root span is explicit, not thread-inherited
-            span = obs.get_tracer().start_span(
-                "serve.deadline", parent=run.qe.ctx, attrs={"qid": ticket.id}
-            )
-        res = run.last if run.last is not None else self._anytime_empty(run)
-        if ticket._finalize(outcome="deadline", result=res):
-            self._record(ticket, blocks=run.qe.counter.stats().blocks_fetched)
-        if self._admission.drop(run):
-            self._retire(run)  # was still queued: safe to tear down here
-        if span is not None:
-            span.end()
+        # runs on the sweeper thread (or a result() waiter); parenting
+        # under the query's root span is explicit, not thread-inherited
+        with obs.span("serve.deadline", parent=run.qe.ctx, qid=ticket.id):
+            res = run.last if run.last is not None else self._anytime_empty(run)
+            if ticket._finalize(outcome="deadline", result=res):
+                self._record(ticket, blocks=run.qe.counter.stats().blocks_fetched)
+            if self._admission.drop(run):
+                self._retire(run)  # was still queued: safe to tear down here
 
     def _anytime_empty(self, run: _Run) -> QueryResult:
         """The anytime answer before any block has been folded: NaN point

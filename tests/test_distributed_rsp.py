@@ -145,12 +145,14 @@ def sig(r):
 assert sig(ref) == sig(res), "survivor diverged:\n%s\n%s" % (sig(ref), sig(res))
 assert sorted(dds.ownership.hosts()) == list(range(t.num_hosts - 1)), dds.ownership.hosts()
 
-# survivors sync through the KV store before exiting: the coordinator
-# (process 0) leaving early would tear the service down under its peer
-t.put("done/%d" % t.host_id, b"1")
-for h in range(t.num_hosts - 1):
-    assert t.get("done/%d" % h, timeout=60.0) is not None
 print("DEAD_HOST_OK", flush=True)
+# the coordinator (process 0) hosts the coordination service, so it leaves
+# last: a peer still running when the service goes away is aborted by it
+if t.host_id == 0:
+    for h in range(1, t.num_hosts - 1):
+        assert t.get("done/%d" % h, timeout=60.0) is not None
+else:
+    t.put("done/%d" % t.host_id, b"1")
 # skip jax.distributed atexit teardown: the coordinator would wait for the
 # killed process's orderly shutdown that never comes
 os._exit(0)

@@ -2,6 +2,9 @@
 pipeline equivalence + exception propagation, LRU cache, sampling policies
 (HT unbiasedness on skewed data), and the similarity self-inclusion fix."""
 
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -15,6 +18,7 @@ from repro.core.sampler import (
 )
 from repro.rsp.engine import (
     BlockExecutor,
+    CallerStats,
     MemoryFetcher,
     MmapFetcher,
     StoreFetcher,
@@ -442,6 +446,30 @@ def test_stats_under_prefetch_pipeline():
         s = ex.stats()
     assert s.hits + s.misses == 6
     assert s.misses >= 4  # at least the four distinct blocks were fetched
+
+
+def test_closing_a_stream_waits_for_its_running_fetches():
+    """A stream closed early (a query that converged) leaves none of its
+    fetches running, so its counter is final once ``close()`` returns."""
+    started = threading.Event()
+
+    class Slow(MemoryFetcher):
+        def fetch(self, block_id):
+            if block_id:
+                started.set()
+                time.sleep(0.2)
+            return super().fetch(block_id)
+
+    counter = CallerStats()
+    with BlockExecutor(Slow(_blocks(k=8)), prefetch=3, cache_blocks=0) as ex:
+        stream = ex.map_blocks(None, range(8), counter=counter)
+        next(stream)
+        assert started.wait(5.0)
+        stream.close()
+        closed = counter.stats()
+        time.sleep(0.5)
+        assert counter.stats() == closed
+    assert closed.misses >= 2  # block 0 and a prefetch that was running
 
 
 def test_reset_stats():

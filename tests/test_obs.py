@@ -6,7 +6,6 @@ through engine/query/serve (including the deadline sweeper), and the
 import json
 import math
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -140,8 +139,7 @@ def test_bounded_buffer_counts_drops():
 
 def test_export_chrome_is_loadable(tmp_path):
     tr = Tracer()
-    with tr.span("op", attrs={"k": "v"}):
-        pass
+    tr.start_span("op", attrs={"k": "v"}).end()
     path = tmp_path / "trace.json"
     n = tr.export_chrome(path)
     payload = json.loads(path.read_text())
@@ -228,37 +226,42 @@ def test_query_spans_propagate_to_engine_workers():
     assert any(f["tid"] != root["tid"] for f in fetches)
 
 
-class _SlowFetcher:
-    """MemoryFetcher with a per-fetch delay: keeps serve queries alive long
-    enough for deadlines to fire deterministically."""
+class _GatedFetcher:
+    """MemoryFetcher whose reads wait for ``gate``: the serve workers stay
+    inside their step until the test opens it, so only the deadline
+    sweeper can finalize a ticket meanwhile."""
 
-    def __init__(self, blocks, delay: float):
+    def __init__(self, blocks, gate: threading.Event):
         self._inner = MemoryFetcher(blocks)
-        self._delay = delay
+        self._gate = gate
 
     @property
     def num_blocks(self) -> int:
         return self._inner.num_blocks
 
     def fetch(self, block_id: int):
-        time.sleep(self._delay)
+        self._gate.wait()
         return self._inner.fetch(block_id)
 
 
 def test_deadline_sweeper_span_parents_under_query():
     obs.enable()
     ds = rsp.partition(_data(blocks=32), blocks=32, seed=0)
+    gate = threading.Event()
     ds._executor = BlockExecutor(
-        _SlowFetcher(ds._blocks, delay=0.03), prefetch=2, cache_blocks=64
+        _GatedFetcher(ds._blocks, gate), prefetch=2, cache_blocks=64
     )
     with ds.serve(workers=2, seed=0) as svc:
         t = svc.submit(
-            "median", target_rel_err=1e-9, use_sketches=False, deadline_ms=150
+            "median", target_rel_err=1e-9, use_sketches=False, deadline_ms=50
         )
         # wait on the ticket (NOT svc.result): only the sweeper thread can
         # finalize it, which is exactly the cross-thread hop under test
-        assert t.wait(10.0)
-        assert t.outcome == "deadline"
+        try:
+            assert t.wait(30.0)
+            assert t.outcome == "deadline"
+        finally:
+            gate.set()  # only now may the worker's step read its block
     ds.close()
     xs = [e for e in obs.get_tracer().chrome_events() if e["ph"] == "X"]
     roots = [e for e in xs if e["name"] == "query"]
@@ -308,6 +311,133 @@ def test_mixed_serve_workload_trace_is_well_formed(tmp_path):
     assert children
     assert all(c["args"]["trace_id"] in root_traces for c in children)
     assert len({e["tid"] for e in xs}) >= 3  # submitters, workers, engine pool
+
+
+# ---------------------------------------------------------------------------
+# Profiler sink: spans in the JAX profiler's trace
+# ---------------------------------------------------------------------------
+
+#: Every span the program opens at a layer boundary of its timed paths.
+SPANS = (
+    "serve.step", "engine.wait", "engine.fetch", "query.fold", "kernel.h2d",
+    "kernel.readback", "query.ci", "partition.shuffle", "partition.sketch",
+    "sketch.block", "store.write", "store.block", "store.sketch", "query.setup",
+    "shuffle.block",
+)
+
+
+def _profiled(log_dir, fn):
+    """Run ``fn`` inside a profiler session; return its result and the
+    host events of the trace as ``(line, name, start_ns, end_ns, stats)``,
+    ``line`` naming one thread."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(log_dir / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = []
+    for p, plane in enumerate(ProfileData.from_file(path).planes):
+        if plane.name.startswith("/device:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                events.append(((p, i), e.name, e.start_ns, e.start_ns + e.duration_ns,
+                               dict(e.stats)))
+    return out, events
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    # metrics on and every trace dropped, as a traced benchmark run has it:
+    # the profiler sink does not depend on the tracer's sampling
+    obs.enable(sample_rate=0.0)
+    data = _data(blocks=8, n=64, f=3)
+    f = data.shape[1]
+
+    def work():
+        ds = rsp.partition(data, blocks=8, backend="pallas", seed=0,
+                           out=str(tmp_path / "store"))
+        with ds.serve(workers=2, seed=0) as svc:
+            tickets = [
+                svc.submit("median", use_sketches=False, max_blocks=3, sketch_impl="jax")
+                for _ in range(2)
+            ]
+            tickets.append(svc.submit("mean", where="c0 > 1.0", max_blocks=2,
+                                      sketch_impl="jax"))
+            for t in tickets:
+                assert t.wait(60.0) and t.result is not None
+        ds.close()
+        return ds, tickets
+
+    h2d = obs.get_registry().counter("rsp_h2d_bytes_total", kernel="block_sketch")
+    (ds, tickets), events = _profiled(tmp_path / "trace", work)
+    names = {e[1] for e in events}
+    assert set(SPANS) <= names, sorted(set(SPANS) - names)
+
+    # the pool's fetches run on engine worker threads, never on a step's
+    steps = [e for e in events if e[1] == "serve.step"]
+    fetch_lines = {e[0] for e in events if e[1] == "engine.fetch"}
+    assert fetch_lines and not fetch_lines & {e[0] for e in steps}
+
+    # a query's set-up, each block's fold and interval update nest inside a
+    # step, on its thread
+    for e in events:
+        if e[1] in ("query.setup", "query.fold", "query.ci"):
+            assert any(s[0] == e[0] and s[2] <= e[2] and e[3] <= s[3] for s in steps), e
+
+    # attributes are the events' stats; the copy names its kernel and bytes
+    block_bytes = ds.block_size * f * 4
+    copies = [e for e in events if e[1] == "kernel.h2d" and e[4].get("kernel") == "block_sketch"]
+    assert copies and all(e[4]["bytes"] == block_bytes for e in copies)
+
+    # bytes counted: one block per block folded by a device impl
+    reg = obs.get_registry()
+    folded = sum(t.result.blocks_read for t in tickets)
+    device_folds = (reg.counter("rsp_h2d_bytes_total", kernel="block_sketch").value
+                    + reg.counter("rsp_h2d_bytes_total", kernel="plan").value)
+    assert h2d.value > 0 and device_folds == folded * block_bytes
+    # the partition copied each original block to the device once
+    assert reg.counter("rsp_h2d_bytes_total", kernel="rsp_shuffle").value == data.nbytes
+    assert len(obs.get_tracer()) == 0  # the tracer sampled nothing
+
+
+def test_span_off_path_builds_no_annotation(monkeypatch, tmp_path):
+    from repro.obs import trace as obs_trace
+
+    built = []
+
+    class Counting(obs_trace.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", Counting)
+    assert not obs.enabled()
+    ds = rsp.partition(_data(blocks=8, n=64), blocks=8, backend="pallas", seed=0,
+                       out=str(tmp_path / "store"))
+    with ds.serve(workers=2, seed=0) as svc:
+        t = svc.submit("median", use_sketches=False, max_blocks=2, sketch_impl="jax")
+        assert t.wait(60.0)
+    ds.close()
+    assert built == []
+    assert obs.span("x") is obs.span("y")  # one shared no-op
+    # the same call builds one once a session collects
+
+    def probe():
+        with obs.span("probe", k=1):
+            pass
+
+    _, events = _profiled(tmp_path / "trace", probe)
+    assert built == ["probe"]
+    assert any(e[1] == "probe" and e[4] == {"k": 1} for e in events)
 
 
 # ---------------------------------------------------------------------------
