@@ -26,11 +26,13 @@ reads suites without change.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.estimators import MomentStats
 from repro.core.moments import chan_merge
 
@@ -259,7 +261,13 @@ class _KLLColumn:
     ``2**h``; level capacities shrink geometrically (ratio 2/3) below the top
     so total space is ``O(k)``.  Compaction keeps every other item of a
     sorted over-full level (random even/odd offset, seeded from the sketch's
-    own compaction counter -- fully deterministic given fold order)."""
+    own compaction counter -- fully deterministic given fold order).
+
+    Two paths fold values in.  Into a fresh column (:attr:`fresh`) the
+    compactions only take strided runs of the sorted values, so
+    :meth:`fill_fresh` reads the levels off the sorted column at positions
+    :func:`_fresh_layout` finds; every other update, and every merge, runs
+    the compactor stack."""
 
     __slots__ = ("k", "levels", "n", "seed", "compactions")
 
@@ -281,6 +289,23 @@ class _KLLColumn:
 
     def _size(self) -> int:
         return sum(lv.size for lv in self.levels)
+
+    @property
+    def fresh(self) -> bool:
+        """Nothing folded in yet: one empty level, no compaction."""
+        return (
+            self.n == 0 and self.compactions == 0
+            and len(self.levels) == 1 and self.levels[0].size == 0
+        )
+
+    def fill_fresh(self, values: np.ndarray, layout) -> None:
+        """Set a fresh column to what :meth:`update` of ``values`` gives, from
+        ``layout = _fresh_layout(values.size, k, seed)``: ``values`` is the
+        column sorted, or as given when the layout has no compaction."""
+        positions, sizes, compactions = layout
+        self.levels = np.split(values[positions], sizes)
+        self.n = int(values.size)
+        self.compactions = compactions
 
     def _cap_total(self) -> int:
         return sum(self._capacity(h) for h in range(len(self.levels)))
@@ -311,19 +336,19 @@ class _KLLColumn:
             else:
                 break
 
+    def _offset(self) -> int:
+        return _compaction_offset(self.seed & 0xFFFFFFFF, self.compactions)
+
     def _compact(self, h: int) -> None:
         if h == len(self.levels) - 1:
             self.levels.append(self._EMPTY)
         buf = np.sort(self.levels[h])
-        rng = np.random.default_rng(
-            np.random.SeedSequence([self.seed & 0xFFFFFFFF, 0x6B11, self.compactions])
-        )
+        offset = self._offset()
         self.compactions += 1
         keep = self._EMPTY
         if buf.size % 2 == 1:           # odd leftover stays at this level
             keep = buf[-1:]
             buf = buf[:-1]
-        offset = int(rng.integers(0, 2))
         self.levels[h + 1] = np.concatenate([self.levels[h + 1], buf[offset::2]])
         self.levels[h] = keep
 
@@ -372,6 +397,69 @@ class _KLLColumn:
         return col
 
 
+def _ties_share_bits(values: np.ndarray) -> bool:
+    """Whether items of the sorted ``values`` that sort as equal also share
+    their bits -- no zeros of both signs, no NaNs of two payloads -- so that
+    no sort, whatever order it leaves ties in, changes the column's bits."""
+    zeros = values[np.searchsorted(values, 0.0, "left"):np.searchsorted(values, 0.0, "right")]
+    signs = np.signbit(zeros)
+    if signs.any() and not signs.all():
+        return False
+    nans = values[np.searchsorted(values, np.nan, "left"):].view(np.uint64)
+    return not nans.size or bool((nans == nans[0]).all())
+
+
+@functools.lru_cache(maxsize=1 << 16)
+def _compaction_offset(seed: int, compaction: int) -> int:
+    """The even/odd offset of a column's ``compaction``-th compaction,
+    memoised: it depends on nothing else."""
+    rng = np.random.default_rng(np.random.SeedSequence([seed, 0x6B11, compaction]))
+    return int(rng.integers(0, 2))
+
+
+class _PositionReplay(_KLLColumn):
+    """A column whose compactions take their even/odd offsets from a given
+    tuple (0 past its end) in place of the seed's draws."""
+
+    __slots__ = ("offsets",)
+
+    def __init__(self, k: int, offsets: tuple[int, ...]):
+        super().__init__(k, 0)
+        self.offsets = offsets
+
+    def _offset(self) -> int:
+        i = self.compactions
+        return self.offsets[i] if i < len(self.offsets) else 0
+
+
+@functools.lru_cache(maxsize=4096)
+def _replay_positions(n: int, k: int, offsets: tuple[int, ...]):
+    """The compaction schedule run on the positions ``0..n-1`` of a fresh
+    column with ``offsets``: ``(positions, split points, compactions)``."""
+    col = _PositionReplay(k, offsets)
+    col.update(np.arange(n, dtype=np.float64))
+    positions = np.concatenate(col.levels).astype(np.intp)
+    positions.flags.writeable = False
+    sizes = tuple(np.cumsum([lv.size for lv in col.levels[:-1]]).tolist())
+    return positions, sizes, col.compactions
+
+
+def _fresh_layout(n: int, k: int, seed: int) -> tuple[np.ndarray, tuple[int, ...], int]:
+    """Where the levels of a fresh column read its ``n`` values after one
+    ``update``: ``(positions, split points, compactions)``.  The positions
+    index the sorted values, or the values as given where nothing was
+    compacted.  Found by running the column's own compaction schedule on the
+    positions ``0..n-1`` (:func:`_replay_positions`): each compaction sorts
+    an over-full level, which already is a sorted run of positions, and
+    promotes every other item into the empty level above, so the values'
+    levels are the sorted values read at these positions.  The sizes, and so
+    the number of compactions, follow from ``(n, k)`` alone; the positions
+    from the seed's offsets, of which there are few patterns."""
+    compactions = _replay_positions(n, k, ())[2]
+    offsets = tuple(_compaction_offset(seed & 0xFFFFFFFF, i) for i in range(compactions))
+    return _replay_positions(n, k, offsets)
+
+
 def kll_rank_error_bound(k: int) -> float:
     """Analytic additive rank-error bound for a KLL sketch with parameter
     ``k`` at ~99% confidence: ``eps = 2.296 / k**0.9`` (the constant the
@@ -417,11 +505,35 @@ class KLLSketch(Sketch):
         return self._columns
 
     def update(self, rows) -> "KLLSketch":
+        """Fold ``rows`` into every column.  Into fresh columns (a block's
+        first rows) one sort of each column gives the levels the compactor
+        would build (:func:`_fresh_layout`); every other update, and a column
+        whose equal items differ in their bits, runs the compactor stack."""
         x = _as_rows(rows)
         if x.shape[0] == 0:
             return self
-        for j, col in enumerate(self._ensure_columns(x.shape[1])):
-            col.update(x[:, j])
+        cols = self._ensure_columns(x.shape[1])
+        filled = [False] * len(cols)
+        if all(col.fresh for col in cols):
+            layouts = [_fresh_layout(x.shape[0], self.k, col.seed) for col in cols]
+            by_column = np.array(x.T, order="C")
+            compacts = any(compactions for _, _, compactions in layouts)
+            if compacts:
+                by_column.sort(axis=1)
+            for j, (col, values, layout) in enumerate(zip(cols, by_column, layouts)):
+                if not compacts or _ties_share_bits(values):
+                    col.fill_fresh(values, layout)
+                    filled[j] = True
+        for j, col in enumerate(cols):
+            if not filled[j]:
+                col.update(x[:, j])
+        if obs.enabled():
+            one_sort = sum(filled)
+            for path, count in (("one_sort", one_sort), ("compactor", len(cols) - one_sort)):
+                if count:
+                    obs.get_registry().counter(
+                        "rsp_kll_folds_total", "KLL column updates by fold path", path=path,
+                    ).inc(count)
         return self
 
     def merge(self, other: "KLLSketch") -> "KLLSketch":
@@ -491,9 +603,12 @@ _HASH_SPACE = float(2**64)
 def _splitmix64(x: np.ndarray) -> np.ndarray:
     """Vectorized splitmix64 finalizer over uint64 (wraps mod 2^64)."""
     z = x + _U64(0x9E3779B97F4A7C15)
-    z = (z ^ (z >> _U64(30))) * _U64(0xBF58476D1CE4E5B9)
-    z = (z ^ (z >> _U64(27))) * _U64(0x94D049BB133111EB)
-    return z ^ (z >> _U64(31))
+    z ^= z >> _U64(30)
+    z *= _U64(0xBF58476D1CE4E5B9)
+    z ^= z >> _U64(27)
+    z *= _U64(0x94D049BB133111EB)
+    z ^= z >> _U64(31)
+    return z
 
 
 def _hash_values(values: np.ndarray) -> np.ndarray:
@@ -502,6 +617,17 @@ def _hash_values(values: np.ndarray) -> np.ndarray:
     v = np.asarray(values, dtype=np.float64).copy()
     v[v == 0.0] = 0.0
     return _splitmix64(v.view(np.uint64))
+
+
+def _smallest_distinct(h: np.ndarray, k: int) -> np.ndarray:
+    """The ``k`` smallest distinct values of ``h``, sorted (or all of them,
+    where fewer), in O(n): the distinct values of its ``2k`` smallest items,
+    where those hold ``k``; else ``np.unique`` of the whole."""
+    if h.size > 2 * k:
+        candidates = np.unique(np.partition(h, 2 * k - 1)[: 2 * k])
+        if candidates.size >= k:
+            return candidates[:k]
+    return np.unique(h)
 
 
 @register_sketch
@@ -536,13 +662,18 @@ class DistinctSketch(Sketch):
         return self._columns
 
     def update(self, rows) -> "DistinctSketch":
+        """Fold ``rows`` in: hash each column of one column-major copy, select
+        its ``k`` smallest distinct new hashes in O(n)
+        (:func:`_smallest_distinct`), and keep the ``k`` smallest of their
+        union with the kept ones -- the same set as the union with every new
+        hash."""
         x = _as_rows(rows)
         if x.shape[0] == 0:
             return self
         cols = self._ensure_columns(x.shape[1])
-        for j in range(x.shape[1]):
-            h = np.union1d(cols[j], _hash_values(x[:, j]))
-            cols[j] = h[: self.k]
+        for j, values in enumerate(np.ascontiguousarray(x.T)):
+            h = _smallest_distinct(_hash_values(values), self.k)
+            cols[j] = np.union1d(cols[j], h)[: self.k]
         return self
 
     def merge(self, other: "DistinctSketch") -> "DistinctSketch":

@@ -122,7 +122,9 @@ def summarize_block(
     (``repro.kernels.block_sketch``) -- the same primitive the query layer
     folds at read time -- wrapped unmodified into the suite's ``moments``
     member; the richer members (KLL quantiles, KMV distinct counts) fold the
-    same rows on the host."""
+    same rows on the host, from one column-major copy: KLL from one sort of
+    each column (its columns are fresh), KMV from an O(n) selection of each
+    column's smallest hashes."""
     from repro.kernels.block_sketch import block_sketch_ref
 
     with obs.span("sketch.block", block=block_id):
@@ -138,9 +140,10 @@ def summarize_block(
             kinds=kinds,
         )
         suite.sketches["moments"] = MomentsSketch.from_block_sketch(sk)
+        by_column = np.asfortranarray(x)
         for kind, member in suite.sketches.items():
             if kind != "moments":
-                member.update(x)
+                member.update(by_column)
     return suite
 
 
