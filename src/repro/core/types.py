@@ -19,6 +19,8 @@ import dataclasses
 import json
 from typing import Any
 
+from repro.core.schema import Schema
+
 
 @dataclasses.dataclass(frozen=True)
 class RSPSpec:
@@ -30,6 +32,9 @@ class RSPSpec:
     record_shape: tuple[int, ...] = ()
     dtype: str = "float32"
     seed: int = 0
+    #: the column schema of a typed (int32) table; None for float records,
+    #: whose columns are ``c0..cF-1``
+    schema: Schema | None = None
 
     def __post_init__(self) -> None:
         if self.num_records <= 0 or self.num_blocks <= 0:
@@ -50,6 +55,14 @@ class RSPSpec:
                 "original block size N/P must be divisible by K so sub-blocks"
                 " have uniform size delta = N/(P*K)"
             )
+        if self.schema is not None:
+            if self.dtype != "int32":
+                raise ValueError(f"a typed table holds int32 records, not {self.dtype}")
+            if tuple(self.record_shape) != (len(self.schema),):
+                raise ValueError(
+                    f"records of shape {tuple(self.record_shape)} do not match the"
+                    f" schema's {len(self.schema)} columns"
+                )
 
     @property
     def block_size(self) -> int:
@@ -66,12 +79,19 @@ class RSPSpec:
         return self.num_records // (self.num_original_blocks * self.num_blocks)
 
     def to_json(self) -> str:
-        return json.dumps(dataclasses.asdict(self))
+        raw = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)}
+        if self.schema is None:
+            del raw["schema"]  # float specs read as they always have
+        else:
+            raw["schema"] = self.schema.to_list()
+        return json.dumps(raw)
 
     @classmethod
     def from_json(cls, payload: str) -> "RSPSpec":
         raw: dict[str, Any] = json.loads(payload)
         raw["record_shape"] = tuple(raw.get("record_shape", ()))
+        if raw.get("schema") is not None:
+            raw["schema"] = Schema.from_list(raw["schema"])
         return cls(**raw)
 
 

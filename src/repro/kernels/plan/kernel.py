@@ -25,6 +25,12 @@ Outputs (2D, TPU-friendly):
 * ``nsel  [1, Fp]``     -- total selected rows (all groups, including rows
   whose group label falls outside ``[0, G)``), broadcast along the row:
   Mosaic stores vectors to VMEM, not scalars.
+
+:func:`plan_totals_pallas` is the typed fold of a
+:class:`~repro.kernels.plan.plan.TypedPlan` over int32 blocks: predicates
+compared exactly on the codes, each measure decoded to float32 by its scale
+and summed per group (the mixed radix of the group-by columns' codes), in
+the same single pass.
 """
 
 from __future__ import annotations
@@ -37,7 +43,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.moments import chan_merge
-from repro.kernels.plan.plan import QueryPlan
+from repro.kernels.plan.plan import QueryPlan, TypedPlan, decode_measures, group_keys
 
 _JNP_OPS = {
     "lt": jnp.less,
@@ -192,3 +198,69 @@ def plan_sketch_pallas(
         interpret=interpret,
     )(*inputs)
     return stats, hist, nsel
+
+
+# ---------------------------------------------------------------------------
+# Typed totals: int32 codes, exact predicates, expression sums per group
+# ---------------------------------------------------------------------------
+
+LANES = 128  # each total is one broadcast row of the output
+
+
+def _totals_kernel(x_ref, out_ref, *, plan: TypedPlan, valid_rows, tile_rows):
+    i = pl.program_id(0)
+    x = x_ref[...]                                            # [T, F] int32
+    t = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0) + i * tile_rows
+    mask = row < valid_rows                                   # [T, 1]
+    column = lambda c: x[:, c : c + 1]  # noqa: E731
+    for p in plan.predicates:
+        mask = jnp.logical_and(mask, _JNP_OPS[p.op](column(p.column), jnp.int32(p.code)))
+    values = decode_measures(column, plan.measures, jnp)     # each [T, 1] float32
+    key = group_keys(column, plan.group_by) if plan.group_by else None
+    totals = [jnp.sum(mask.astype(jnp.float32))]
+    for g in range(plan.groups):
+        mg = mask if key is None else jnp.logical_and(mask, key == g)
+        totals.append(jnp.sum(mg.astype(jnp.float32)))
+        totals += [jnp.sum(jnp.where(mg, v, 0.0)) for v in values]
+
+    @pl.when(i == 0)
+    def _init():
+        for r, s in enumerate(totals):
+            out_ref[r, :] = jnp.full((LANES,), s, jnp.float32)
+
+    @pl.when(i > 0)
+    def _fold():
+        for r, s in enumerate(totals):
+            out_ref[r, :] = out_ref[r, :] + s
+
+
+def plan_totals_pallas(
+    x: jax.Array,   # [n, F] int32
+    *,
+    plan: TypedPlan,
+    tile_rows: int,
+    interpret: bool,
+) -> jax.Array:
+    """Run the typed fold; returns ``[1 + G (1 + M), LANES]`` float32 totals
+    (each row broadcast along its lanes): the selected-row count, then per
+    group g its count and its M measure sums.  Padded rows are masked like
+    rows that fail a predicate; sums accumulate in float32 across tiles."""
+    if x.ndim != 2:
+        raise ValueError(f"block must be [n, F], got shape {x.shape}")
+    n, f = x.shape
+    n_tiles = max(1, -(-n // tile_rows))
+    pad = n_tiles * tile_rows - n
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    rows = 1 + plan.groups * (1 + len(plan.measures))
+    kernel = functools.partial(_totals_kernel, plan=plan, valid_rows=n, tile_rows=tile_rows)
+    return pl.pallas_call(
+        kernel,
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec((tile_rows, f), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+        name="plan",
+        interpret=interpret,
+    )(x.astype(jnp.int32))

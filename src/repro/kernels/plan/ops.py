@@ -18,6 +18,12 @@ standing bin-edge caveat):
   moment fold and histogram scatter.  Compiled on a TPU, interpreted
   elsewhere (:func:`repro.runtime.interpret_mode`).
 
+``plan_totals(block, typed_plan)`` is the same for a typed table's int32
+blocks (:class:`~repro.kernels.plan.plan.TypedPlan`): exact predicates on
+the codes, expression measures decoded to float32 and summed per group, in
+the same four implementations; its ``impl="auto"`` is untuned (jax on
+accelerators, fused numpy on CPU).
+
 Kernels are **compiled per plan**: :func:`compile_plan` closes over the
 plan's predicates/columns/groups as constants and memoizes on
 ``(plan.key(), features, bins, impl, tile)`` -- re-running a plan hits the
@@ -41,8 +47,14 @@ from repro.kernels import autotune
 from repro.kernels.autotune import Candidate
 from repro.kernels.block_sketch.ops import _inv_width
 from repro.kernels.block_sketch.ref import BlockSketch, _grid
-from repro.kernels.plan.plan import QueryPlan
-from repro.kernels.plan.ref import PlanResult, plan_sketch_ref
+from repro.kernels.plan.plan import QueryPlan, TypedPlan, decode_measures, group_keys
+from repro.kernels.plan.ref import (
+    PlanResult,
+    PlanTotals,
+    plan_sketch_ref,
+    plan_totals_ref,
+    totals_of,
+)
 from repro.runtime import count_kernel_run, interpret_mode, to_device, to_host
 
 IMPLS = ("auto", "ref", "np", "jax", "pallas")
@@ -52,6 +64,9 @@ NP_TILES = (8192, 16384, 32768, 65536)
 # at F=29, bins=64 (the 3-D histogram one-hot is [T, F, bins])
 PALLAS_TILES = (128, 256, 512)
 DEFAULT_NP_TILE = 16384  # the pinned REPRO_AUTOTUNE=off choice on CPU
+# the typed pallas fold's tile: it keeps no histogram, so larger than the
+# float plan's (4096-row tiles of a 16-column block overflow v5e's scoped VMEM)
+TYPED_PALLAS_TILE = 1024
 
 _CACHE: dict[tuple, Callable] = {}
 _CACHE_LOCK = threading.Lock()
@@ -311,10 +326,120 @@ def _build_pallas(plan, f, bins, tile_rows, interpret):
     return run
 
 
-def _tile(impl: str, tile_rows: int | None) -> int | None:
+# -- typed plans: int32 codes, exact predicates, expression sums per group --
+
+def _to_device_flat(x: np.ndarray):
+    """A typed block's copy to the device, flat: a TPU lays out a narrow
+    ``[n, 16]`` int32 block column-major in tiles, which the host would build
+    by a transposition of every copy; a flat array copies as it lies, and the
+    device reshapes it (the jitted executors take ``flat.reshape(-1, F)``)."""
+    return to_device(np.ascontiguousarray(x).reshape(-1), "plan")
+
+
+def _build_typed_ref(plan):
+    return lambda x: plan_totals_ref(x, plan)
+
+
+def _build_typed_np(plan, tile_rows):
+    """Cache-blocked numpy typed fold: per row tile, mask the codes, take
+    the passing rows, decode the measures (float32) and add their per-group
+    sums into float64 accumulators."""
+    G = plan.groups
+
+    def run(x):
+        counts = np.zeros(G)
+        sums = np.zeros((G, len(plan.measures)))
+        nsel = 0
+        for start in range(0, x.shape[0], tile_rows):
+            t = x[start : start + tile_rows]
+            sel = np.take(t, np.flatnonzero(plan.mask(t)), axis=0)
+            if sel.shape[0] == 0:
+                continue
+            nsel += sel.shape[0]
+            column = lambda c: sel[:, c]  # noqa: E731
+            keys = np.broadcast_to(group_keys(column, plan.group_by), (sel.shape[0],))
+            c, s = totals_of(keys, decode_measures(column, plan.measures), G)
+            counts += c
+            sums += s
+        return PlanTotals(int(x.shape[0]), nsel, counts, sums)
+
+    return run
+
+
+def _typed_jax_fold(plan):
+    """The jitted typed fold ``fused(flat, f)`` of a flat int32 block of
+    ``f`` columns: the selected-row count, per-group counts and sums."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.plan.kernel import _JNP_OPS
+
+    G = plan.groups
+
+    @functools.partial(jax.jit, static_argnums=1)
+    def fused(flat, f):
+        x = flat.reshape(-1, f)
+        column = lambda c: x[:, c]  # noqa: E731
+        m = jnp.ones((x.shape[0],), bool)
+        for p in plan.predicates:
+            m = jnp.logical_and(m, _JNP_OPS[p.op](column(p.column), jnp.int32(p.code)))
+        onehot = m[:, None]
+        if plan.group_by:
+            key = group_keys(column, plan.group_by)
+            onehot = jnp.logical_and(onehot, key[:, None] == jnp.arange(G, dtype=jnp.int32))
+        counts = onehot.sum(axis=0, dtype=jnp.int32)
+        values = decode_measures(column, plan.measures, jnp)
+        sums = (jnp.stack([jnp.where(onehot, v[:, None], 0.0).sum(axis=0) for v in values], 1)
+                if values else jnp.zeros((G, 0), jnp.float32))
+        return m.sum(dtype=jnp.int32), counts, sums
+
+    return fused
+
+
+def _build_typed_jax(plan):
+    G, M = plan.groups, len(plan.measures)
+    fused = _typed_jax_fold(plan)
+
+    def run(x):
+        nsel, counts, sums = to_host(fused(_to_device_flat(x), x.shape[1]), "plan")
+        return PlanTotals(int(x.shape[0]), int(nsel), np.asarray(counts, np.float64),
+                          np.asarray(sums, np.float64).reshape(G, M))
+
+    return run
+
+
+def _build_typed_pallas(plan, tile_rows, interpret):
+    import functools
+
+    import jax
+
+    from repro.kernels.plan.kernel import plan_totals_pallas
+
+    G, M = plan.groups, len(plan.measures)
+
+    @functools.partial(jax.jit, static_argnums=1)
+    def fused(flat, f):
+        return plan_totals_pallas(flat.reshape(-1, f), plan=plan, tile_rows=tile_rows,
+                                  interpret=interpret)
+
+    def run(x):
+        (out,) = to_host((fused(_to_device_flat(x), x.shape[1]),), "plan")
+        t = np.asarray(out[:, 0], np.float64)
+        per_group = t[1:].reshape(G, 1 + M)
+        return PlanTotals(int(x.shape[0]), int(t[0]), per_group[:, 0].copy(),
+                          per_group[:, 1:].copy())
+
+    return run
+
+
+def _tile(impl: str, tile_rows: int | None, typed: bool = False) -> int | None:
     """The tile a tiled impl runs with when none is pinned."""
     if impl in ("np", "pallas") and tile_rows is None:
-        return DEFAULT_NP_TILE if impl == "np" else PALLAS_TILES[0]
+        if impl == "np":
+            return DEFAULT_NP_TILE
+        return TYPED_PALLAS_TILE if typed else PALLAS_TILES[0]
     return tile_rows
 
 
@@ -332,7 +457,8 @@ def compile_plan(
     global _HITS, _MISSES
     if impl not in IMPLS or impl == "auto":
         raise ValueError(f"compile_plan impl must be concrete, got {impl!r}")
-    tile_rows = _tile(impl, tile_rows)
+    typed = isinstance(plan, TypedPlan)
+    tile_rows = _tile(impl, tile_rows, typed)
     interpret = impl == "pallas" and interpret_mode()
     key = (plan.key(), int(num_features), int(bins), impl, tile_rows, interpret)
     telemetry = obs.enabled()
@@ -346,7 +472,15 @@ def compile_plan(
                 ).inc()
             return fn
     t0 = time.perf_counter()
-    if impl == "ref":
+    if typed and impl == "ref":
+        fn = _build_typed_ref(plan)
+    elif typed and impl == "np":
+        fn = _build_typed_np(plan, tile_rows)
+    elif typed and impl == "jax":
+        fn = _build_typed_jax(plan)
+    elif typed:
+        fn = _build_typed_pallas(plan, tile_rows, interpret)
+    elif impl == "ref":
         fn = _build_ref(plan, num_features, bins)
     elif impl == "np":
         fn = _build_np(plan, num_features, bins, tile_rows)
@@ -451,3 +585,33 @@ def plan_sketch(
     fn = compile_plan(plan, num_features=f, bins=bins, impl=impl, tile_rows=tile_rows)
     count_kernel_run("plan", impl, tile_rows if impl in ("np", "pallas") else None)
     return fn(x, glo, ghi)
+
+
+def plan_totals(block, plan: TypedPlan, *, impl: str = "auto",
+                tile_rows: int | None = None) -> PlanTotals:
+    """Execute a :class:`TypedPlan` over one int32 block ``[n, F]`` in one
+    pass: per group, the count of rows passing the predicates and each
+    measure's float32 sum over them.  ``impl="auto"`` runs ``jax`` on an
+    accelerator and ``np`` on a CPU, untuned: on a TPU v5e the jax fold
+    takes less device time than the pallas one, and a one-call timing
+    picked between them differently from run to run.  With telemetry on,
+    ``rsp_plan_rows_total{outcome=scanned|selected}`` counts the rows, so a
+    run shows each plan's selectivity."""
+    if impl not in IMPLS:
+        raise ValueError(f"unknown impl {impl!r} (one of {IMPLS})")
+    x = np.asarray(block)
+    if x.dtype != np.int32:
+        raise ValueError(f"a typed fold reads int32 codes, got {x.dtype}")
+    x = x.reshape(x.shape[0], -1)
+    if impl == "auto":
+        impl = _default_candidate().impl
+    tile_rows = _tile(impl, tile_rows, typed=True)
+    fn = compile_plan(plan, num_features=x.shape[1], impl=impl, tile_rows=tile_rows)
+    count_kernel_run("plan", impl, tile_rows if impl in ("np", "pallas") else None)
+    res = fn(x)
+    if obs.enabled():
+        reg = obs.get_registry()
+        for outcome, rows in (("scanned", res.rows_total), ("selected", res.rows_selected)):
+            reg.counter("rsp_plan_rows_total", "rows a typed fold read and passed",
+                        outcome=outcome).inc(rows)
+    return res

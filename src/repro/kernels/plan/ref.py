@@ -16,7 +16,7 @@ import dataclasses
 import numpy as np
 
 from repro.kernels.block_sketch.ref import BlockSketch, _grid, block_sketch_ref
-from repro.kernels.plan.plan import QueryPlan
+from repro.kernels.plan.plan import QueryPlan, TypedPlan, decode_measures, group_keys
 
 
 def empty_sketch(
@@ -90,3 +90,43 @@ def plan_sketch_ref(
     return PlanResult(
         rows_total=int(n), rows_selected=int(sel.shape[0]), sketches=sketches
     )
+
+
+@dataclasses.dataclass
+class PlanTotals:
+    """Outcome of one typed fold (:class:`~repro.kernels.plan.plan.TypedPlan`)
+    over one block: how many rows it held and how many passed, and per
+    group the count of passing rows (``counts [G]``) and each measure's sum
+    over them (``sums [G, M]``)."""
+
+    rows_total: int
+    rows_selected: int
+    counts: np.ndarray
+    sums: np.ndarray
+
+
+def totals_of(keys: np.ndarray, values: list, groups: int) -> tuple[np.ndarray, np.ndarray]:
+    """Per-group counts and float64 sums of each ``values`` array over rows
+    with group ``keys`` (rows whose key lies outside ``[0, groups)`` drop)."""
+    keys = np.asarray(keys, np.int64)
+    ok = (keys >= 0) & (keys < groups)
+    if not ok.all():
+        keys, values = keys[ok], [v[ok] for v in values]
+    counts = np.bincount(keys, minlength=groups).astype(np.float64)
+    sums = np.zeros((groups, len(values)))
+    for j, v in enumerate(values):
+        sums[:, j] = np.bincount(keys, weights=v.astype(np.float64), minlength=groups)
+    return counts, sums
+
+
+def plan_totals_ref(block, plan: TypedPlan) -> PlanTotals:
+    """Reference typed fold: mask the int32 codes, materialize the passing
+    rows, decode each measure to float32 (:func:`decode_measures`) and sum
+    per group in float64."""
+    x = np.asarray(block).reshape(np.shape(block)[0], -1)
+    sel = x[plan.mask(x)]
+    column = lambda c: sel[:, c]  # noqa: E731
+    values = decode_measures(column, plan.measures)
+    keys = np.broadcast_to(group_keys(column, plan.group_by), (sel.shape[0],))
+    counts, sums = totals_of(keys, values, plan.groups)
+    return PlanTotals(int(x.shape[0]), int(sel.shape[0]), counts, sums)

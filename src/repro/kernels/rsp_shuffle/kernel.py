@@ -16,6 +16,10 @@ The composition (tile dealing o intra-tile shuffle) is a bijection and is
 exactly the structure Algorithm 1 needs: locally randomize, slice into
 delta-chunks, deal chunks to output blocks (Lemma 1 applies at slice
 granularity).  ``ref.py`` is the equivalent flat row-gather.
+
+Float blocks go through the matmul as they are; int32 blocks (typed
+tables) as their four bytes, each exact in bfloat16, so every bit pattern
+survives.
 """
 
 from __future__ import annotations
@@ -28,6 +32,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _permute(onehot, tile):
+    # HIGHEST keeps the f32 contraction exact: each output row is 1.0 times
+    # one input row, so a reduced-precision pass would round the records
+    return jax.lax.dot_general(
+        onehot, tile, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _shuffle_kernel(tile_perm_ref, intra_ref, x_ref, o_ref):
     del tile_perm_ref  # consumed by the index_map
     tile = x_ref[...]                       # [T, D] (gathered tile)
@@ -35,14 +49,23 @@ def _shuffle_kernel(tile_perm_ref, intra_ref, x_ref, o_ref):
     T = tile.shape[0]
     # one-hot permutation matrix on the MXU: onehot[r, c] = (c == perm[r])
     cols = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    onehot = (cols == perm).astype(tile.dtype)
-    # HIGHEST keeps the f32 contraction exact: each output row is 1.0 times
-    # one input row, so a reduced-precision pass would round the records
-    o_ref[...] = jax.lax.dot_general(
-        onehot, tile, (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    ).astype(o_ref.dtype)
+    onehot = cols == perm
+    if tile.dtype == jnp.int32:
+        # int32 moves as its four bytes, each an exact bfloat16 value, through
+        # single-pass bf16 matmuls (a bitcast to float would make small codes
+        # float denormals, which the MXU flushes to zero), then is put back
+        # together bit for bit
+        onehot = onehot.astype(jnp.bfloat16)
+        out = None
+        for shift in (0, 8, 16, 24):
+            byte = ((tile >> shift) & 0xFF).astype(jnp.float32).astype(jnp.bfloat16)
+            moved = jax.lax.dot_general(
+                onehot, byte, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            ).astype(jnp.int32) << shift
+            out = moved if out is None else out | moved
+        o_ref[...] = out
+    else:
+        o_ref[...] = _permute(onehot.astype(tile.dtype), tile).astype(o_ref.dtype)
 
 
 def rsp_shuffle_pallas(

@@ -2,6 +2,11 @@
 apply the kernel.  ``rsp_randomize_block`` is the on-device realization of
 Algorithm 1's per-block randomize step.
 
+With ``whole_block=True`` the block's rows are first permuted as a whole (a
+device gather), so each tile the kernel deals is a uniformly random subset
+of the block, as Algorithm 1 has it; without it a tile is a run of
+consecutive rows, shuffled within itself.
+
 ``tile_rows=None`` (the default) asks the shared autotuner for the fastest
 tile among the divisors of ``R``; passing an explicit tile pins it -- the
 partition backends do exactly that (``tile_rows=delta``) because the tile
@@ -34,11 +39,15 @@ def make_permutations(key: jax.Array, n_tiles: int, tile_rows: int):
     return tile_perm, intra
 
 
-@functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
-def _randomize(x: jax.Array, key: jax.Array, *, tile_rows: int, interpret: bool) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("tile_rows", "interpret", "whole_block"))
+def _randomize(x: jax.Array, key: jax.Array, *, tile_rows: int, interpret: bool,
+               whole_block: bool = False) -> jax.Array:
     R = x.shape[0]
     if R % tile_rows:
         raise ValueError(f"R={R} must be divisible by tile_rows={tile_rows}")
+    if whole_block:
+        key, k_rows = jax.random.split(key)
+        x = jnp.take(x, jax.random.permutation(k_rows, R), axis=0)
     tile_perm, intra = make_permutations(key, R // tile_rows, tile_rows)
     return rsp_shuffle_pallas(x, tile_perm, intra, tile_rows=tile_rows, interpret=interpret)
 
@@ -74,17 +83,19 @@ def _auto_tile(x: jax.Array) -> int:
 
 
 def rsp_randomize_block(
-    x: jax.Array, key: jax.Array, *, tile_rows: int | None = None
+    x: jax.Array, key: jax.Array, *, tile_rows: int | None = None, whole_block: bool = False
 ) -> jax.Array:
     """Randomize one original block [R, D] on-device (hierarchical shuffle).
 
     ``tile_rows=None`` autotunes over the divisors of ``R``; an explicit
     tile is honored verbatim (and is part of the shuffle's definition --
-    two calls with different tiles produce different permutations)."""
+    two calls with different tiles produce different permutations).
+    ``whole_block`` permutes all ``R`` rows before the tiles are dealt."""
     if tile_rows is None:
         tile_rows = _auto_tile(x)
     count_kernel_run("rsp_shuffle", "pallas", tile_rows)
-    return _randomize(x, key, tile_rows=tile_rows, interpret=interpret_mode())
+    return _randomize(x, key, tile_rows=tile_rows, interpret=interpret_mode(),
+                      whole_block=whole_block)
 
 
 _shuffle = jax.jit(rsp_shuffle_pallas, static_argnames=("tile_rows", "interpret"))

@@ -5,7 +5,7 @@ different execution substrate and declares a *capability predicate* that
 says whether it can serve a given request:
 
     np        -- paper-faithful numpy in-memory path; the fallback for
-                 non-float / non-2D array data.
+                 other dtypes and non-2D array data.
     np_stream -- out-of-core single-pass scatter (``repro.rsp.ingest``):
                  anything ``as_chunk_source`` can adapt (memmapped ``.npy``,
                  chunk-file directories, record-batch iterators, arrays)
@@ -16,7 +16,7 @@ says whether it can serve a given request:
                  requires a mesh with P = K = mesh size.
     pallas    -- the ``rsp_shuffle`` TPU kernel: hierarchical tile shuffle
                  per original block with the delta-slice dealing expressed
-                 as DMA scheduling; requires 2-D floating-point data and,
+                 as DMA scheduling; requires 2-D float or int32 data and,
                  where it compiles (a TPU), a delta that fills whole
                  sublane tiles.
 
@@ -294,8 +294,13 @@ def _supports_pallas(req: PartitionRequest) -> str | None:
     if len(shape) != 2:
         return f"kernel needs 2-D [records, features] data, got shape {shape}"
     dtype = getattr(req.data, "dtype", None)
-    if dtype is None or not np.issubdtype(np.dtype(dtype), np.floating):
-        return f"kernel shuffles via an MXU matmul and needs a float dtype, got {dtype}"
+    if dtype is None or not (
+        np.issubdtype(np.dtype(dtype), np.floating) or np.dtype(dtype) == np.int32
+    ):
+        return (
+            "kernel shuffles via an MXU matmul and needs a float or int32 dtype,"
+            f" got {dtype}"
+        )
     if not req.permute_assignment:
         return "sub-block assignment permutation is intrinsic to the tile dealing"
     # compiled, the kernel's tile is delta rows of the block, and Mosaic
@@ -323,8 +328,11 @@ def _run_pallas(req: PartitionRequest) -> np.ndarray:
 
     Per original block, ``tile_rows = delta`` makes the kernel's tile
     permutation *be* the sub-block dealing: output tile k of block i is the
-    (intra-shuffled) sub-block destined for RSP block k.  Lemma 1 applies at
-    slice granularity (see kernels.rsp_shuffle.kernel).
+    (intra-shuffled) sub-block destined for RSP block k.  Float blocks deal
+    runs of consecutive rows, so Lemma 1 applies at slice granularity (see
+    kernels.rsp_shuffle.kernel); int32 blocks (typed tables, often stored
+    in key order) have all their rows permuted first, so each sub-block is
+    a random sample of its original block, as Algorithm 1 has it.
 
     Original blocks stream through the device one at a time, so device
     memory holds one block, not the corpus and its shuffled copies.
@@ -334,13 +342,14 @@ def _run_pallas(req: PartitionRequest) -> np.ndarray:
     data = np.asarray(req.data)
     R, F = spec.original_block_size, data.shape[1]
     key = jax.random.PRNGKey(spec.seed)
+    whole_block = data.dtype == np.int32
     out = np.empty((K, P * delta, F), jax.dtypes.canonicalize_dtype(data.dtype))
     with obs.span("partition.shuffle", blocks=P):
         for i in range(P):
             with obs.span("shuffle.block", block=i):
                 sub = rsp_randomize_block(
                     to_device(data[i * R : (i + 1) * R], "rsp_shuffle"),
-                    jax.random.fold_in(key, i), tile_rows=delta,
+                    jax.random.fold_in(key, i), tile_rows=delta, whole_block=whole_block,
                 )
                 # tile k of original block i is its sub-block dealt to RSP block k
                 (sub,) = to_host((sub,), "rsp_shuffle")

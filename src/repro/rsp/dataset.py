@@ -42,6 +42,7 @@ from repro.core.ensemble import (
 )
 from repro.core.estimators import BlockLevelEstimator, MomentStats, streaming_estimate
 from repro.core.registry import RSPStore
+from repro.core.schema import Schema
 from repro.core.sampler import (
     BlockSampler,
     HostAssignment,
@@ -121,6 +122,7 @@ class RSPDataset:
         summaries: bool = True,
         out: str | None = None,
         chunk_records: int | None = None,
+        schema: Schema | Sequence[dict] | None = None,
     ) -> "RSPDataset":
         """Partition ``data`` [N, ...] into an RSP of ``blocks`` blocks.
 
@@ -142,7 +144,15 @@ class RSPDataset:
         ``label_column`` as a class label so label histograms join the
         per-block summaries and ``.ensemble`` / ``.label_divergence`` know
         how to split records.
+
+        ``schema`` (a :class:`~repro.core.schema.Schema`, or its list of
+        column dicts) makes a typed table: ``data`` is then ``[N, F]`` int32
+        codes, one column per schema column, stored bit-exact, and queries
+        name columns, compare literals in their units, sum expressions and
+        group by dictionary columns (see :class:`repro.rsp.query.Query`).
         """
+        if schema is not None and not isinstance(schema, Schema):
+            schema = Schema.from_list(schema)
         # memmaps are ndarrays: when an in-memory backend is forced they stay
         # raw (it serves them fine); under auto/np_stream they stream
         src = None
@@ -164,6 +174,7 @@ class RSPDataset:
             record_shape=record_shape,
             dtype=dtype,
             seed=seed,
+            schema=schema,
         )
         request = PartitionRequest(
             data=data,

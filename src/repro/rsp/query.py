@@ -56,11 +56,21 @@ from repro import obs
 from repro.core.estimators import quantile_from_histogram
 from repro.core.sampler import SamplingPolicy, UniformPolicy, WeightedPolicy
 from repro.kernels.block_sketch import BlockSketch, block_sketch
-from repro.kernels.plan import Predicate, QueryPlan, as_predicates, plan_sketch
+from repro.kernels.plan import (
+    NamedPredicate,
+    Predicate,
+    QueryPlan,
+    as_where,
+    bind_plan,
+    plan_sketch,
+    plan_totals,
+)
 from repro.obs.convergence import ConvergenceStep, ConvergenceTrace
 from repro.rsp.engine import CallerStats, ExecutorStats
 
-KINDS = ("mean", "var", "sum", "count", "quantile", "histogram", "distinct")
+KINDS = ("mean", "var", "sum", "count", "quantile", "histogram", "distinct", "avg")
+#: the aggregates of a typed query: Horvitz-Thompson totals and their ratio
+TOTAL_KINDS = ("sum", "count", "avg")
 _SKETCH_ONLY_KINDS = ("mean", "var", "sum", "count")
 _EPS = 1e-12
 
@@ -139,6 +149,11 @@ class Aggregate:
     column.  ``by_label=True`` computes the aggregate per class (needs
     ``num_classes`` on the dataset); the result gains a leading class axis.
     ``quantile`` needs ``q`` in (0, 1).
+
+    Over a typed table (a dataset with a column schema), ``sum`` and
+    ``avg`` take an ``expr``: a product of columns, each as it is or as
+    ``(1 - c)`` / ``(1 + c)``, in the columns' units
+    (``"l_extendedprice * (1 - l_discount)"``); ``count`` counts rows.
     """
 
     kind: str
@@ -146,6 +161,7 @@ class Aggregate:
     feature: int | None = None
     by_label: bool = False
     name: str | None = None
+    expr: str | None = None
 
     def __post_init__(self):
         if self.kind not in KINDS:
@@ -157,11 +173,17 @@ class Aggregate:
             raise ValueError(f"q= only applies to quantile aggregates, not {self.kind!r}")
         if self.kind == "distinct" and self.by_label:
             raise ValueError("distinct aggregates do not support by_label")
+        if self.expr is not None and self.kind not in ("sum", "avg"):
+            raise ValueError(f"expr= applies to sum and avg aggregates, not {self.kind!r}")
+        if self.kind == "avg" and self.expr is None:
+            raise ValueError("avg aggregates need an expr= over a typed table")
 
     @property
     def label(self) -> str:
         if self.name:
             return self.name
+        if self.expr is not None:
+            return f"{self.kind}({self.expr})"
         s = self.kind if self.q is None else f"p{self.q * 100:g}"
         if self.feature is not None:
             s += f"[{self.feature}]"
@@ -228,6 +250,19 @@ class Query:
     progressive scan reads the blocks that matter for *this* query first
     (Horvitz-Thompson reweighting keeps the estimates unbiased).
 
+    Over a typed table ``where=`` names columns and gives literals in their
+    units (``"l_shipdate <= date '1998-09-02'"``, ``"l_discount >= 0.05"``,
+    ``"l_returnflag == 'R'"``), compared exactly on the int32 codes, and
+    ``group_by=`` names dictionary columns (the group of a row is the mixed
+    radix of their codes).  Such a query takes ``sum`` / ``count`` / ``avg``
+    aggregates, folds each block through one typed plan
+    (:func:`~repro.kernels.plan.plan_totals`), and answers every group's
+    Horvitz-Thompson total ``(K / b) sum_k t_k`` over the ``b`` blocks read
+    with a Student-t interval and the finite-population correction (``avg``
+    as the ratio of two totals, its interval linearized); reading all ``K``
+    blocks gives the exact answer with a zero-width interval.  The stopping
+    rule holds every one of them to ``target_rel_err``.
+
     ``seed`` drives block selection and the bootstrap; ``None`` (the
     default) means "no seed pinned": direct execution falls back to 0, and
     a :class:`~repro.serve.QueryService` replaces it with
@@ -246,15 +281,19 @@ class Query:
     bootstrap: int = 200
     use_sketches: bool | str = "auto"
     sketch_impl: str = "auto"
-    where: tuple[Predicate, ...] = ()
+    where: tuple[Predicate | NamedPredicate, ...] = ()
     columns: tuple[int, ...] | None = None
+    group_by: tuple[str, ...] = ()
     #: record a convergence step after *every* block (not only when the
     #: stopping rule forces result materialization), so ``result.trace``
     #: reproduces the paper's error-vs-blocks trajectory at full resolution
     explain: bool = False
 
     def __post_init__(self):
-        self.where = as_predicates(self.where)
+        self.where = as_where(self.where)
+        self.group_by = (self.group_by,) if isinstance(self.group_by, str) else tuple(
+            self.group_by
+        )
         if self.columns is not None:
             self.columns = tuple(int(c) for c in self.columns)
             if not self.columns:
@@ -328,6 +367,10 @@ class QueryResult:
     executor_stats: ExecutorStats | None = None
     selectivity: float | None = None
     trace: ConvergenceTrace | None = None
+    #: a typed query's groups answered (those with rows among the blocks
+    #: read), each the tuple of its group-by values, in the order of the
+    #: estimates' leading axis; None for other queries
+    groups: tuple[tuple[str, ...], ...] | None = None
 
     def __getitem__(self, name: str) -> AggregateResult:
         for a in self.aggregates:
@@ -652,6 +695,69 @@ class _DistinctAgg:
         return AggregateResult(self.agg.label, "distinct", est, None, None, None)
 
 
+class _Totals:
+    """A typed query's per-block fold results, and from them every
+    aggregate's Horvitz-Thompson estimate per group.
+
+    Under uniform sampling of ``b`` of ``K`` blocks without replacement, a
+    group's total is ``K / b`` times the sum of its block totals ``t_k``,
+    with the Student-t half-width ``t * fpc * K * sd(t_k) / sqrt(b)``; an
+    ``avg`` is the ratio of two such totals, its half-width from the
+    residuals ``y_k - R x_k`` over ``sqrt(b) * mean(x_k)``."""
+
+    def __init__(self, aggregates, measure_of: dict, keys: list, ctx: _Ctx):
+        self.aggregates = aggregates
+        self.measure_of = measure_of
+        self.keys = keys            # the group-by values of each group, or None
+        self.ctx = ctx
+        self.counts: list[np.ndarray] = []
+        self.sums: list[np.ndarray] = []
+
+    def add(self, res) -> None:
+        self.counts.append(res.counts)
+        self.sums.append(res.sums)
+
+    def _total(self, t: np.ndarray):
+        b, K = t.shape[0], self.ctx.K
+        est = K * t.sum(axis=0) / b
+        if b < 2:
+            return est, np.full(est.shape, np.inf)
+        sd = t.std(axis=0, ddof=1)
+        return est, self.ctx.t_half(b) * self.ctx.fpc(b) * K * sd / math.sqrt(b)
+
+    def _ratio(self, y: np.ndarray, x: np.ndarray):
+        b = y.shape[0]
+        r = y.sum(axis=0) / x.sum(axis=0)
+        if b < 2:
+            return r, np.full(r.shape, np.inf)
+        sd = (y - r * x).std(axis=0, ddof=1)
+        return r, self.ctx.t_half(b) * self.ctx.fpc(b) * sd / (math.sqrt(b) * x.mean(axis=0))
+
+    def results(self) -> tuple[tuple[AggregateResult, ...], tuple | None]:
+        counts = np.stack(self.counts)                       # [b, G]
+        grouped = self.keys is not None
+        # a grouped answer holds the groups with rows among the blocks read
+        seen = np.flatnonzero(counts.sum(axis=0) > 0) if grouped else [0]
+        counts = counts[:, seen]
+        sums = np.stack(self.sums)[:, seen]                  # [b, g, M]
+        out = []
+        for a in self.aggregates:
+            with np.errstate(invalid="ignore", divide="ignore"):  # an avg of no rows is NaN
+                if a.kind == "count":
+                    est, half = self._total(counts)
+                elif a.kind == "sum":
+                    est, half = self._total(sums[:, :, self.measure_of[a.expr]])
+                else:
+                    est, half = self._ratio(sums[:, :, self.measure_of[a.expr]], counts)
+                rel = float(np.max(half / np.maximum(np.abs(est), _EPS))) if est.size else 0.0
+            lo, hi = est - half, est + half
+            if not grouped:
+                est, lo, hi = (float(v[0]) for v in (est, lo, hi))
+            out.append(AggregateResult(a.label, a.kind, est, lo, hi, rel))
+        groups = tuple(self.keys[g] for g in seen) if grouped else None
+        return tuple(out), groups
+
+
 def _stack_groups(values: list, by_label: bool):
     """Stack per-class results into a leading class axis (NaN for classes
     not yet observed); scalar-ize ungrouped single-element results."""
@@ -718,6 +824,46 @@ class QueryExecutor:
         # where= / columns= route block passes through the plan-compiled
         # fused kernels instead of the legacy whole-block sketch
         self.planned = bool(query.where) or query.columns is not None
+        # a typed query folds every block through one typed plan
+        self.tplan = self._typed_plan() if self._is_typed() else None
+
+    def _is_typed(self) -> bool:
+        q = self.q
+        return (
+            bool(q.group_by)
+            or any(a.expr is not None or a.kind == "avg" for a in q.aggregates)
+            or any(isinstance(p, NamedPredicate) for p in q.where)
+        )
+
+    def _typed_plan(self):
+        q = self.q
+        schema = self.ds.spec.schema
+        if schema is None:
+            raise ValueError(
+                "named predicates, expressions and group_by need a typed table"
+                " (partition it with schema=...)"
+            )
+        bad = [a.label for a in q.aggregates if a.kind not in TOTAL_KINDS or a.by_label]
+        if bad or q.columns is not None:
+            raise ValueError(
+                f"a typed query takes sum, count and avg aggregates (not {bad}) and no columns="
+            )
+        self._measure_of: dict[str, int] = {}
+        for a in q.aggregates:
+            if a.expr is not None:
+                self._measure_of.setdefault(a.expr, len(self._measure_of))
+        return bind_plan(schema, q.where, list(self._measure_of), q.group_by)
+
+    def _group_keys(self) -> list | None:
+        """The group-by values of each group of the typed plan, in its
+        mixed-radix order (the last column varies fastest)."""
+        if not self.q.group_by:
+            return None
+        dicts = [self.ds.spec.schema.column(n).dictionary for n in self.q.group_by]
+        keys = [()]
+        for d in dicts:
+            keys = [k + (v,) for k in keys for v in d]
+        return keys
 
     @property
     def ctx(self):
@@ -756,7 +902,7 @@ class QueryExecutor:
         return callable(getattr(s, "get", None)) and s.get(kind) is not None
 
     def _sketch_eligible(self) -> bool:
-        if self.q.where:
+        if self.q.where or self.tplan is not None:
             # partition-time sketches are unfiltered; a predicate needs rows
             return False
         for a in self.q.aggregates:
@@ -1056,6 +1202,8 @@ class QueryExecutor:
         execution bit-identical to single-host: any host computing this
         block's payload produces the same dict, so *where* it is computed is
         irrelevant to the fold."""
+        if self.tplan is not None:
+            return {"totals": plan_totals(block, self.tplan, impl=self.q.sketch_impl)}
         payload = self._block_sketches(block, lo, hi, needs_hist, grouped, need_whole)
         payload["distinct"] = (
             self._distinct_sketch(block) if needs_rows else None
@@ -1139,6 +1287,10 @@ class QueryExecutor:
             if q.use_sketches is True or res.converged:
                 yield res
                 return
+
+        if self.tplan is not None:
+            yield from self._totals_stream(anytime=anytime)
+            return
 
         # sketch probabilities (weighted/stratified) and the histogram grid
         # both come from ds.summaries, which on a sketch-less dataset reads
@@ -1258,6 +1410,75 @@ class QueryExecutor:
         finally:
             # GeneratorExit / convergence must reach the source's own finally
             # (a distributed source publishes its stop marker there)
+            source.close()
+
+    def _totals_stream(self, *, anytime: bool) -> Iterator[QueryResult]:
+        """The progressive path of a typed query: one typed fold per block,
+        then every group's totals and intervals (``query.totals``)."""
+        q = self.q
+        self._pol = self.ds.policy(q.policy, seed=self.seed)
+        if not isinstance(self._pol, UniformPolicy):
+            raise ValueError("typed totals expand uniform samples of blocks: use policy='uniform'")
+        K = self.ds.num_blocks
+        max_blocks = min(q.max_blocks if q.max_blocks is not None else K, K)
+        if max_blocks < 1:
+            raise ValueError("max_blocks must be >= 1")
+        ctx = _Ctx(K=K, N=self.ds.spec.num_records, confidence=q.confidence, uniform=True,
+                   num_classes=None, bootstrap=q.bootstrap, seed=self.seed)
+        totals = _Totals(q.aggregates, self._measure_of, self._group_keys(), ctx)
+
+        def gen_ids():
+            for _ in range(max_blocks):
+                yield self._pol.sample(1)[0]
+
+        b = 0
+        sel_rows = tot_rows = 0
+        trace = ConvergenceTrace(confidence=q.confidence, target_rel_err=q.target_rel_err)
+        source = self._payload_source(gen_ids(), None, None, needs_hist=False, needs_rows=False,
+                                      grouped=False, need_whole=True)
+        try:
+            for bid, payload in source:
+                res = payload["totals"]
+                totals.add(res)
+                sel_rows += res.rows_selected
+                tot_rows += res.rows_total
+                b += 1
+                if not (anytime or q.explain or q.target_rel_err is not None or b == max_blocks):
+                    continue
+                with obs.span("query.totals", parent=self.ctx, blocks=b):
+                    results, groups = totals.results()
+                errs = [r.rel_err for r in results]
+                converged = (q.target_rel_err is not None and b >= q.min_blocks
+                             and max(errs) <= q.target_rel_err)
+                trace.record(
+                    ConvergenceStep(
+                        blocks_read=b,
+                        block_id=int(bid),
+                        max_rel_err=max(errs),
+                        estimates={r.name: _scalar0(r.estimate) for r in results},
+                        half_widths={r.name: _half_width(r) for r in results},
+                        cum_fetch_s=self.counter.fetch_seconds(),
+                        elapsed_s=time.perf_counter() - self._t0,
+                    )
+                )
+                if converged:
+                    source.close()
+                yield QueryResult(
+                    aggregates=results,
+                    blocks_read=b,
+                    total_blocks=K,
+                    confidence=q.confidence,
+                    target_rel_err=q.target_rel_err,
+                    converged=converged,
+                    from_sketches=False,
+                    executor_stats=self.counter.stats(),
+                    selectivity=sel_rows / max(tot_rows, 1) if q.where else None,
+                    trace=trace,
+                    groups=groups,
+                )
+                if converged:
+                    return
+        finally:
             source.close()
 
     def run(self) -> QueryResult:

@@ -613,8 +613,12 @@ def _splitmix64(x: np.ndarray) -> np.ndarray:
 
 def _hash_values(values: np.ndarray) -> np.ndarray:
     """Hash float64 values by bit pattern (with ``-0.0`` canonicalized to
-    ``+0.0`` so equal values always collide)."""
-    v = np.asarray(values, dtype=np.float64).copy()
+    ``+0.0`` so equal values always collide); integer values (the int32
+    codes of a typed table) by their own bits, sign-extended to 64."""
+    values = np.asarray(values)
+    if np.issubdtype(values.dtype, np.integer):
+        return _splitmix64(values.astype(np.int64).view(np.uint64))
+    v = values.astype(np.float64)  # always a copy
     v[v == 0.0] = 0.0
     return _splitmix64(v.view(np.uint64))
 
@@ -667,7 +671,8 @@ class DistinctSketch(Sketch):
         (:func:`_smallest_distinct`), and keep the ``k`` smallest of their
         union with the kept ones -- the same set as the union with every new
         hash."""
-        x = _as_rows(rows)
+        x = np.asarray(rows)
+        x = x.reshape(x.shape[0], -1) if np.issubdtype(x.dtype, np.integer) else _as_rows(x)
         if x.shape[0] == 0:
             return self
         cols = self._ensure_columns(x.shape[1])
@@ -852,9 +857,11 @@ class SketchSuite:
 
     # -- Sketch protocol, suite-wide --------------------------------------
     def update(self, rows) -> "SketchSuite":
-        x = _as_rows(rows)
+        raw = np.asarray(rows)
+        x = _as_rows(raw)
         for sk in self.sketches.values():
-            sk.update(x)
+            # KMV hashes integer codes by their own bits
+            sk.update(raw if isinstance(sk, DistinctSketch) else x)
         return self
 
     def merge(self, other: "SketchSuite") -> "SketchSuite":

@@ -124,7 +124,8 @@ def summarize_block(
     member; the richer members (KLL quantiles, KMV distinct counts) fold the
     same rows on the host, from one column-major copy: KLL from one sort of
     each column (its columns are fresh), KMV from an O(n) selection of each
-    column's smallest hashes."""
+    column's smallest hashes.  An int32 block (a typed table) folds its
+    exact integers: moments and KLL in float64, KMV over the codes' bits."""
     from repro.kernels.block_sketch import block_sketch_ref
 
     with obs.span("sketch.block", block=block_id):
@@ -141,9 +142,13 @@ def summarize_block(
         )
         suite.sketches["moments"] = MomentsSketch.from_block_sketch(sk)
         by_column = np.asfortranarray(x)
+        # KMV hashes a typed table's int32 codes by their own bits
+        raw = np.asarray(block)
+        ints = (np.asfortranarray(raw.reshape(raw.shape[0], -1))
+                if np.issubdtype(raw.dtype, np.integer) else by_column)
         for kind, member in suite.sketches.items():
             if kind != "moments":
-                member.update(by_column)
+                member.update(ints if kind == "distinct" else by_column)
     return suite
 
 

@@ -9,6 +9,9 @@ break the TPU tiling rule, scalar stores to VMEM, and tiles that overflow
 VMEM.  The CPU tests below check that the code asks for compiled kernels
 when a TPU is the backend.
 
+The typed ``plan`` kernel and the int32 ``rsp_shuffle`` compile at the
+TPC-H lineitem cell's shapes (141,312 x 16 int32).
+
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU library, and every test worker imports this file.
 """
@@ -104,6 +107,58 @@ def test_rsp_shuffle_compiles_for_v5e(one_chip, tile):
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
     _compile_has_kernel(
         lambda x, k: rs_ops._randomize(x, k, tile_rows=tile, interpret=False), x, key
+    )
+
+
+# the TPC-H lineitem cell: one original block of N / P = 18,087,936 / 128
+# int32 records of 16 columns, dealt in slices of 1,104
+TPCH_ROWS, TPCH_COLUMNS, TPCH_DELTA = 141_312, 16, 1_104
+
+
+def _typed_plans():
+    from repro.data import tpch
+    from repro.kernels.plan import as_where, bind_plan
+
+    schema = tpch.lineitem_schema()
+    return {
+        "q1": bind_plan(schema, as_where(tpch.q1_where(90)),
+                        [e for _, _, e in tpch.Q1 if e], tpch.Q1_GROUP_BY),
+        "q6": bind_plan(schema, as_where(tpch.q6_where("1994-01-01", 0.06, 24)),
+                        ["l_extendedprice * l_discount"]),
+    }
+
+
+@pytest.mark.parametrize("plan", ("q1", "q6"))
+def test_typed_plan_kernel_compiles_for_v5e(one_chip, plan):
+    from repro.kernels.plan.kernel import plan_totals_pallas
+
+    typed = _typed_plans()[plan]
+    # a block reaches the device flat (plan.ops._to_device_flat)
+    flat = jax.ShapeDtypeStruct((TPCH_ROWS * TPCH_COLUMNS,), jnp.int32, sharding=one_chip)
+    _compile_has_kernel(
+        lambda flat: plan_totals_pallas(flat.reshape(-1, TPCH_COLUMNS), plan=typed,
+                                        tile_rows=plan_ops.TYPED_PALLAS_TILE, interpret=False),
+        flat,
+    )
+
+
+@pytest.mark.parametrize("plan", ("q1", "q6"))
+def test_typed_jax_fold_compiles_for_v5e(one_chip, plan):
+    # the typed fold that plan_totals runs on a TPU (impl="auto")
+    flat = jax.ShapeDtypeStruct((TPCH_ROWS * TPCH_COLUMNS,), jnp.int32, sharding=one_chip)
+    fold = plan_ops._typed_jax_fold(_typed_plans()[plan])
+    assert "fusion" in fold.lower(flat, TPCH_COLUMNS).compile().as_text()
+
+
+@pytest.mark.parametrize("tile", (TPCH_DELTA, 256))
+def test_int32_rsp_shuffle_compiles_for_v5e(one_chip, tile):
+    # as the pallas backend runs it on a typed table: the whole block's rows
+    # permuted (a gather), then the tiles dealt
+    x = jax.ShapeDtypeStruct((TPCH_ROWS, TPCH_COLUMNS), jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    _compile_has_kernel(
+        lambda x, k: rs_ops._randomize(x, k, tile_rows=tile, interpret=False, whole_block=True),
+        x, key,
     )
 
 
