@@ -380,6 +380,9 @@ class DistributedDataset:
     def has_summaries(self) -> bool:
         return self.dataset.has_summaries
 
+    def merged_sketch(self, kind: str):
+        return self.dataset.merged_sketch(kind)
+
     @property
     def executor(self) -> BlockExecutor:
         return self._executor

@@ -28,6 +28,7 @@ estimates unbiased.
 
 from __future__ import annotations
 
+import threading
 from typing import Any, Callable, Sequence
 
 import jax
@@ -62,7 +63,13 @@ from repro.rsp.engine import (
     StoreFetcher,
     as_fetcher,
 )
-from repro.rsp.sketch import SketchSuite, load_summaries, sketch_schema_descriptor
+from repro.rsp.sketch import (
+    Sketch,
+    SketchSuite,
+    load_summaries,
+    sketch_from_dict,
+    sketch_schema_descriptor,
+)
 from repro.rsp.summaries import (
     BlockSummary,
     combine_summaries,
@@ -97,6 +104,11 @@ class RSPDataset:
         self._blocks = None if blocks is None else np.asarray(blocks)
         self._store = store
         self._summaries = summaries
+        # merged_sketch's memo: one corpus-level sketch per kind, for the
+        # summaries list it was merged from
+        self._merged_lock = threading.Lock()
+        self._merged_from = None
+        self._merged: dict[str, Sketch] = {}
         self._fetcher_mode = fetcher
         self._prefetch = prefetch
         self._cache_blocks = cache_blocks
@@ -343,6 +355,45 @@ class RSPDataset:
         """Whether partition-time sketches are already materialized (without
         triggering the full-corpus pass that computes them)."""
         return self._summaries is not None
+
+    def merged_sketch(self, kind: str) -> Sketch:
+        """Corpus-level sketch of one kind: the union of the per-block
+        sketches in block order, merged into a deep copy of the first (the
+        stored suites are never mutated).  Built once per summaries list and
+        kind -- a query that asks while another builds waits for that build,
+        and replacing the summaries builds anew -- then shared by every query
+        of this dataset, so callers only read it (``quantile``,
+        ``rank_error_bound``, ``estimate``, ``relative_error_bound``)."""
+        summaries = self.summaries
+        with self._merged_lock:
+            if self._merged_from is not summaries:
+                self._merged_from, self._merged = summaries, {}
+            outcome = "reused"
+            if kind not in self._merged:
+                merged = None
+                for s in summaries:
+                    sk = s.get(kind) if callable(getattr(s, "get", None)) else None
+                    if sk is None:
+                        raise ValueError(
+                            f"sketch-only answers need {kind!r} sketches in the"
+                            " manifest (re-partition the store, or pass"
+                            " use_sketches=False)"
+                        )
+                    if merged is None:
+                        merged = sketch_from_dict(sk.to_dict())
+                    else:
+                        merged.merge(sk)
+                self._merged[kind] = merged
+                outcome = "built"
+            merged = self._merged[kind]
+        if obs.enabled():
+            obs.get_registry().counter(
+                "rsp_merged_sketch_total",
+                "corpus-level merged sketches by outcome",
+                kind=kind,
+                outcome=outcome,
+            ).inc()
+        return merged
 
     def _compute_summaries(self, counter=None) -> list[SketchSuite]:
         label_column = self.label_column if self.num_classes is not None else None

@@ -920,26 +920,6 @@ class QueryExecutor:
                 return False
         return True
 
-    def _merged_sketch(self, summaries, kind: str):
-        """Corpus-level sketch of one kind: union of the per-block sketches
-        (fresh object -- the stored suites are never mutated)."""
-        from repro.rsp.sketch import sketch_from_dict
-
-        acc = None
-        for s in summaries:
-            sk = s.get(kind) if callable(getattr(s, "get", None)) else None
-            if sk is None:
-                raise ValueError(
-                    f"sketch-only answers need {kind!r} sketches in the"
-                    " manifest (re-partition the store, or pass"
-                    " use_sketches=False)"
-                )
-            if acc is None:
-                acc = sketch_from_dict(sk.to_dict())
-            else:
-                acc.merge(sk)
-        return acc
-
     def _answer_from_sketches(self) -> QueryResult:
         from repro.rsp.summaries import combine_summaries
 
@@ -960,13 +940,6 @@ class QueryExecutor:
         def shape(v):
             v = np.asarray(v, dtype=np.float64)
             return float(v) if v.ndim == 0 else v
-
-        merged_cache: dict = {}
-
-        def merged(kind):
-            if kind not in merged_cache:
-                merged_cache[kind] = self._merged_sketch(summaries, kind)
-            return merged_cache[kind]
 
         out = []
         for a in self.q.aggregates:
@@ -989,7 +962,7 @@ class QueryExecutor:
                 # KLL: point at rank q, interval at ranks q -+ eps -- the
                 # sketch's additive rank-error bound, mapped through the
                 # value axis (an honest, data-dependent interval)
-                kll = merged("kll")
+                kll = self.ds.merged_sketch("kll")
                 eps = kll.rank_error_bound()
                 vals = kll.quantile(
                     [max(a.q - eps, 0.0), a.q, min(a.q + eps, 1.0)]
@@ -1002,7 +975,7 @@ class QueryExecutor:
                     np.max(half / np.maximum(np.abs(np.asarray(est)), _EPS))
                 )
             else:  # distinct: KMV estimate with its known relative SE
-                kmv = merged("distinct")
+                kmv = self.ds.merged_sketch("distinct")
                 rel = float(kmv.relative_error_bound())
                 est = _sel(proj(kmv.estimate()), a.feature)
                 lo_v = shape(np.asarray(est) * (1.0 - rel))
@@ -1062,7 +1035,7 @@ class QueryExecutor:
         summaries = self._materialized_summaries()
         lo = np.min([s.min for s in summaries], axis=0).astype(np.float64)
         hi = np.max([s.max for s in summaries], axis=0).astype(np.float64)
-        tight = self._kll_grid(summaries, lo, hi)
+        tight = self._kll_grid(lo, hi)
         if tight is not None:
             lo, hi = tight
         pad = np.maximum(1e-9, 1e-9 * (hi - lo))
@@ -1072,7 +1045,7 @@ class QueryExecutor:
             lo, hi = lo[cols], hi[cols]
         return lo, hi
 
-    def _kll_grid(self, summaries, lo, hi):
+    def _kll_grid(self, lo, hi):
         """KLL-seeded ``(lo, hi)``, or None to keep the extrema grid.  Only
         safe when every grid consumer is an ungrouped, unfiltered quantile:
         filtered or per-class distributions can concentrate in a corpus
@@ -1087,7 +1060,7 @@ class QueryExecutor:
         ):
             return None
         try:
-            kll = self._merged_sketch(summaries, "kll")
+            kll = self.ds.merged_sketch("kll")
         except ValueError:  # v1 suites: no KLL -> extrema grid
             return None
         eps = kll.rank_error_bound()
