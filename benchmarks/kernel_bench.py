@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.runtime import interpret_mode, use_compile_cache
+from repro.runtime import use_compile_cache
 
 Row = tuple  # (name, value, derived[, metrics dict])
 
@@ -127,28 +127,21 @@ def bench_ssd_and_wkv() -> list[Row]:
     return rows
 
 
-def bench_block_sketch() -> list[Row]:
-    from repro.kernels.block_sketch import block_sketch
-    from repro.kernels.block_sketch.kernel import block_sketch_pallas
+def bench_plan_whole_block() -> list[Row]:
+    """The plan an unfiltered query folds each block through: no
+    predicates, every column, moments and histogram in one pass."""
+    from repro.kernels.plan import QueryPlan, plan_sketch
 
     rows = []
     n, f, bins = 16_384, 16, 128
-    x = jax.random.normal(jax.random.PRNGKey(4), (n, f), jnp.float32) * 2.0 + 1.5
-    lo = jnp.full((f,), -8.0)
-    inv_w = jnp.full((f,), bins / 16.0)
+    x = np.random.default_rng(4).normal(1.5, 2.0, (n, f)).astype(np.float32)
+    kw = dict(bins=bins, lo=-8.0, hi=8.0)
     gb = n * f * 4 / 1e9
-    us = _timeit(
-        lambda: jax.block_until_ready(
-            block_sketch_pallas(
-                x, lo, inv_w, bins=bins, tile_rows=512, interpret=interpret_mode()
-            )[0]
-        ),
-        repeat=1,
-    )
-    rows.append(("block_sketch_pallas_interp_16k", us, f"gbps={gb / (us / 1e6):.3f}"))
-    xs = np.asarray(x)
-    us = _timeit(lambda: block_sketch(xs, bins=bins, lo=-8.0, hi=8.0, impl="jax"))
-    rows.append(("block_sketch_jax_fused_16k", us, f"gbps={gb / (us / 1e6):.3f}"))
+    us = _timeit(lambda: plan_sketch(x, QueryPlan(), impl="pallas", tile_rows=512, **kw),
+                 repeat=1)
+    rows.append(("plan_whole_pallas_16k", us, f"gbps={gb / (us / 1e6):.3f}"))
+    us = _timeit(lambda: plan_sketch(x, QueryPlan(), impl="jax", **kw))
+    rows.append(("plan_whole_jax_16k", us, f"gbps={gb / (us / 1e6):.3f}"))
     return rows
 
 
@@ -274,7 +267,7 @@ ALL_KERNELS = [
     bench_flash_attention,
     bench_rsp_shuffle,
     bench_ssd_and_wkv,
-    bench_block_sketch,
+    bench_plan_whole_block,
     bench_plan_kernels,
 ]
 

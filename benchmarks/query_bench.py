@@ -12,7 +12,8 @@ Three measurements, mirroring what ``repro.rsp.query`` is for:
    versus scanning every block.
 
 3. **Fused sketch kernel** -- records/sec of the fused moments+histogram
-   sketch (``repro.kernels.block_sketch``, ``impl="auto"``) against the
+   pass an unfiltered query folds each block through (the plan with no
+   predicates, ``repro.kernels.plan``, ``impl="auto"``) against the
    two-pass equivalent (separate moments and histogram sweeps) it replaces.
    On CPU these are plumbing numbers (both paths are RAM-resident); the
    single-HBM-pass win is the Pallas kernel's TPU story, like the other
@@ -41,7 +42,7 @@ import numpy as np
 
 from repro import rsp
 from repro.core.estimators import block_histogram, block_moments
-from repro.kernels.block_sketch import block_sketch
+from repro.kernels.plan import QueryPlan, plan_sketch
 from repro.runtime import use_compile_cache
 
 
@@ -100,7 +101,7 @@ def bench_fused_sketch(block: np.ndarray, *, bins: int = 128, repeats: int = 10)
             fn()
         return n * repeats / (time.perf_counter() - t0)
 
-    fused = timed(lambda: block_sketch(block, bins=bins, lo=lo, hi=hi, impl="auto"))
+    fused = timed(lambda: plan_sketch(block, QueryPlan(), bins=bins, lo=lo, hi=hi, impl="auto"))
     two_pass = timed(
         lambda: (block_moments(block), block_histogram(block, bins=bins, lo=-8, hi=8))
     )

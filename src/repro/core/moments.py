@@ -1,7 +1,7 @@
 """The one Chan et al. parallel moment-combine.
 
 Every layer that folds per-block moments -- ``core.estimators``, the
-``block_sketch`` reference and Pallas kernels, and the ``rsp.sketch``
+``block_sketch`` reference, the ``plan`` kernels, and the ``rsp.sketch``
 suite -- routes through :func:`chan_merge` so the algebra lives in exactly
 one place.  The helper is array-namespace generic (``xp=np`` by default,
 ``xp=jax.numpy`` inside jitted/Pallas code) and operates on the raw

@@ -137,9 +137,7 @@ class DistributedQueryExecutor(QueryExecutor):
         self.presumed_dead: set[int] = set()
 
     # -- the one overridden seam -------------------------------------------
-    def _payload_source(
-        self, ids, lo, hi, *, needs_hist, needs_rows, grouped, need_whole
-    ) -> Iterator[tuple[int, dict]]:
+    def _payload_source(self, ids, fold) -> Iterator[tuple[int, dict]]:
         dds = self._dds
         transport: Transport = dds.transport
         me = transport.host_id
@@ -148,7 +146,7 @@ class DistributedQueryExecutor(QueryExecutor):
         ids = [int(i) for i in ids]
         n = len(ids)
 
-        ns, base, fp = self._namespace(ids, lo, hi)
+        ns, base, fp = self._namespace(ids, fold)
         transport.put(f"{base}/fp/{me}", fp.encode())
 
         ownership = dds.ownership
@@ -164,11 +162,7 @@ class DistributedQueryExecutor(QueryExecutor):
 
         def compute(p: int) -> bytes:
             block = dds.executor.fetch(ids[p], counter=self.counter)
-            data = encode_payload(
-                self._make_payload(
-                    block, lo, hi, needs_hist, needs_rows, grouped, need_whole
-                )
-            )
+            data = encode_payload(fold.payload(block))
             transport.put(f"{ns}/p/{p}", data)
             computed[p] = data
             sched.complete(me, p)
@@ -244,16 +238,16 @@ class DistributedQueryExecutor(QueryExecutor):
                 pass  # dying hosts cannot say goodbye
 
     # -- naming and divergence detection -----------------------------------
-    def _namespace(self, ids, lo, hi) -> tuple[str, str, str]:
+    def _namespace(self, ids, fold) -> tuple[str, str, str]:
         """``(ns, base, fp)`` for this query's keys.
 
         ``base`` digests the query *shape* (seed, aggregates, predicates,
         stopping rule); ``fp`` digests the *derived state* (policy
-        distribution, materialized id sequence, histogram grid).  The
-        working namespace is ``base/fp``, so hosts whose manifests diverge
-        can never consume each other's payloads -- divergence degrades to
-        isolated (still correct) execution, and ``_check_fingerprints``
-        turns it into a loud error."""
+        distribution, materialized id sequence, the fold's histogram
+        grid).  The working namespace is ``base/fp``, so hosts whose
+        manifests diverge can never consume each other's payloads --
+        divergence degrades to isolated (still correct) execution, and
+        ``_check_fingerprints`` turns it into a loud error."""
         q = self.q
         sig = {
             "seed": self.seed,
@@ -277,9 +271,7 @@ class DistributedQueryExecutor(QueryExecutor):
         except NotImplementedError:  # custom policy: fall back to its name
             h.update(getattr(self._pol, "name", "custom").encode())
         h.update(np.asarray(ids, dtype=np.int64).tobytes())
-        if lo is not None:
-            h.update(np.ascontiguousarray(np.asarray(lo, np.float64)).tobytes())
-            h.update(np.ascontiguousarray(np.asarray(hi, np.float64)).tobytes())
+        h.update(fold.fingerprint())
         fp = h.hexdigest()[:16]
         return f"{base}/{fp}", base, fp
 

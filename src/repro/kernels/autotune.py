@@ -29,8 +29,8 @@ module replaces the constants with a tiny measured search:
   touches no files.  CI and the tier-1 tests run in this mode, so test
   outcomes never depend on machine-local timings.
 
-Consumers: ``repro.kernels.plan`` (fused query-plan kernels),
-``repro.kernels.block_sketch`` (``impl="auto"`` + Pallas tile), and
+Consumers: ``repro.kernels.plan`` (the fused query-plan kernels that
+every float block fold runs, ``impl="auto"`` + tile) and
 ``repro.kernels.rsp_shuffle`` (``tile_rows=None``).
 """
 

@@ -1,16 +1,13 @@
-"""Fused per-block sketch kernel: one pass -> moments + extrema + histogram.
+"""The per-block sketch container and its numpy oracle.
 
-The query subsystem's per-block hot loop (``repro.rsp.query``) and the
-partition-time summaries both reduce to this sketch; ``ops.block_sketch``
-dispatches between the numpy oracle, the jit'd jax path, and the Pallas TPU
-kernel (``ref.py`` / ``ops.py`` / ``kernel.py``).
+:class:`BlockSketch` holds one block's count, per-feature mean / M2 /
+extrema and optional fixed-grid histogram; :func:`merge_sketches` combines
+two.  :func:`block_sketch_ref` computes one in float64 -- the oracle that
+the plan kernels' reference (``repro.kernels.plan.ref``) and the ingest
+sketches use.  On the query path blocks fold through the plan kernels
+(``repro.kernels.plan``), which return these containers.
 """
 
-from repro.kernels.block_sketch.ops import (
-    IMPLS,
-    batched_block_sketch,
-    block_sketch,
-)
 from repro.kernels.block_sketch.ref import (
     BlockSketch,
     block_sketch_ref,
@@ -19,10 +16,7 @@ from repro.kernels.block_sketch.ref import (
 )
 
 __all__ = [
-    "IMPLS",
     "BlockSketch",
-    "batched_block_sketch",
-    "block_sketch",
     "block_sketch_ref",
     "grid_histogram",
     "merge_sketches",

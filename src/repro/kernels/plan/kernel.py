@@ -9,9 +9,8 @@ entirely in VMEM:
    columns (MXU-friendly; identity plans skip it);
 3. **Grouped Chan moments** -- per plan group, masked (count, mean, M2,
    min, max) folded across tiles with the parallel combine;
-4. **Histogram scatter** -- the same one-hot-vs-iota trick as
-   ``block_sketch.kernel``, weighted by the mask so rejected rows add zero
-   mass.
+4. **Histogram scatter** -- a one-hot-vs-iota compare per bin, weighted
+   by the mask so rejected rows add zero mass.
 
 Rows that fail a predicate never leave the tile: there is no second
 "apply the mask" pass over HBM, which is the whole point versus the
@@ -76,7 +75,8 @@ def _plan_kernel(
     xp = x @ proj_ref[...] if project else x                  # [T, Fp]
     fp = xp.shape[1]
     if plan.group_by is not None:
-        lab = x[:, plan.group_by : plan.group_by + 1]         # [T, 1] float labels
+        gcol = plan.group_by % f                              # label_column may be -1
+        lab = x[:, gcol : gcol + 1]                           # [T, 1] float labels
 
     groups = []
     for g in range(plan.groups):

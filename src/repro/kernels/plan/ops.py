@@ -45,7 +45,6 @@ import numpy as np
 from repro import obs
 from repro.kernels import autotune
 from repro.kernels.autotune import Candidate
-from repro.kernels.block_sketch.ops import _inv_width
 from repro.kernels.block_sketch.ref import BlockSketch, _grid
 from repro.kernels.plan.plan import QueryPlan, TypedPlan, decode_measures, group_keys
 from repro.kernels.plan.ref import (
@@ -90,6 +89,13 @@ def cache_clear() -> None:
 # ---------------------------------------------------------------------------
 # Implementations (each factory returns run(x32, glo, ghi) -> PlanResult)
 # ---------------------------------------------------------------------------
+
+def _inv_width(lo: np.ndarray, hi: np.ndarray, bins: int) -> np.ndarray:
+    """Per-feature ``bins / (hi - lo)``; 0 for a constant feature, whose
+    mass then lands in bin 0."""
+    width = (hi - lo) / max(bins, 1)
+    return np.where(width > 0, 1.0 / np.where(width > 0, width, 1.0), 0.0)
+
 
 def _result(plan, fp, bins, glo, ghi, *, nsel, n, cnt, mean, m2, mn, mx, hist):
     """Assemble numpy per-group stats into a PlanResult."""
@@ -572,15 +578,13 @@ def plan_sketch(
     glo = ghi = None
     if bins > 0:
         glo, ghi = _grid(lo, hi, fp)
-    if impl == "pallas" and bins == 0:
-        impl = "jax"
     if impl == "auto":
         cfg = _auto_config(plan, x, glo, ghi, bins=bins)
         impl = cfg.impl
         if tile_rows is None:
             tile_rows = cfg.tile_rows
-        if impl == "pallas" and bins == 0:
-            impl = "jax"
+    if impl == "pallas" and bins == 0:
+        impl = "jax"
     tile_rows = _tile(impl, tile_rows)
     fn = compile_plan(plan, num_features=f, bins=bins, impl=impl, tile_rows=tile_rows)
     count_kernel_run("plan", impl, tile_rows if impl in ("np", "pallas") else None)

@@ -594,7 +594,7 @@ def stream_partition(
             suite = a.suite if a.suite is not None else SketchSuite.create(
                 k, kinds=("moments", "kll", "distinct")
             )
-            suite.sketches["moments"] = MomentsSketch.from_block_sketch(a.sketch)
+            suite.sketches["moments"] = MomentsSketch.wrap(a.sketch)
             if a.label_hist is not None:
                 suite.sketches["labels"] = LabelsSketch(
                     num_classes, label_column, hist=a.label_hist

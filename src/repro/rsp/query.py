@@ -20,12 +20,13 @@ read:
 * **Progressive path** -- otherwise blocks stream one at a time through the
   dataset's prefetching :class:`~repro.rsp.engine.BlockExecutor` under a
   :class:`~repro.core.sampler.SamplingPolicy`.  Each block is folded through
-  the fused one-pass sketch kernel (``repro.kernels.block_sketch``) into
-  combinable per-aggregate state -- Chan moments for ``mean``/``var``/
-  ``sum``/``count``, mergeable fixed-grid histograms for ``quantile``/
-  ``histogram`` -- and after every block an *anytime* :class:`QueryResult`
-  is emitted with confidence intervals.  The stream stops early once every
-  interval is relatively tighter than ``target_rel_err``.
+  the fused one-pass plan kernel (``repro.kernels.plan``; an unfiltered
+  query is a plan with no predicates) into combinable per-aggregate state
+  -- Chan moments for ``mean``/``var``/``sum``/``count``, mergeable
+  fixed-grid histograms for ``quantile``/``histogram`` -- and after every
+  block an *anytime* :class:`QueryResult` is emitted with confidence
+  intervals.  The stream stops early once every interval is relatively
+  tighter than ``target_rel_err``.
 
 Confidence intervals follow the consistency framework of block-level
 estimates (Karmakar & Mukhopadhyay, 2018): each RSP block is a random sample
@@ -55,11 +56,12 @@ import numpy as np
 from repro import obs
 from repro.core.estimators import quantile_from_histogram
 from repro.core.sampler import SamplingPolicy, UniformPolicy, WeightedPolicy
-from repro.kernels.block_sketch import BlockSketch, block_sketch
+from repro.kernels.block_sketch import BlockSketch
 from repro.kernels.plan import (
     NamedPredicate,
     Predicate,
     QueryPlan,
+    TypedPlan,
     as_where,
     bind_plan,
     plan_sketch,
@@ -237,10 +239,13 @@ class Query:
     value)`` tuples, :class:`~repro.kernels.plan.Predicate` instances, or a
     sequence of them).  ``columns=`` projects the answer onto those feature
     columns (``feature=`` on an aggregate then indexes the *projected*
-    axis).  Either one routes execution through the plan-compiled fused
-    kernels (``repro.kernels.plan``): predicates, projection, moments and
-    histograms all happen in one pass per block, and a filtered query
-    reports its observed :attr:`QueryResult.selectivity`.  Queries with
+    axis).  Every block folds through the plan-compiled fused kernels
+    (``repro.kernels.plan``), with or without these: predicates,
+    projection, moments and histograms all happen in one pass per block,
+    and a filtered query reports its observed
+    :attr:`QueryResult.selectivity`.  ``sketch_impl`` pins the plan
+    kernels' implementation (``"ref"`` / ``"np"`` / ``"jax"`` /
+    ``"pallas"``); ``"auto"`` lets the shared autotuner choose.  Queries with
     ``where=`` cannot use the sketch-only fast path (partition-time
     sketches are unfiltered), so ``use_sketches=True`` raises.
 
@@ -674,16 +679,13 @@ class _DistinctAgg:
         self.ctx = ctx
         self.sketch = DistinctSketch()
 
-    def update(self, sketches: Sequence[BlockSketch], weight: float | None) -> None:
-        pass  # fed per-block KMV sketches via merge_block, not moments
-
-    def merge_block(self, block_sketch) -> None:
+    def merge_block(self, kmv) -> None:
         """Fold one block's KMV sketch.  k-min-of-union == union-of-k-mins,
         so merging per-block sketches is *exactly* equal to feeding the raw
         rows -- which is what lets distributed hosts ship sketches instead
         of rows."""
-        if block_sketch is not None:
-            self.sketch = self.sketch.merge(block_sketch)
+        if kmv is not None:
+            self.sketch = self.sketch.merge(kmv)
 
     def result(self) -> AggregateResult:
         try:
@@ -705,6 +707,8 @@ class _Totals:
     ``avg`` is the ratio of two such totals, its half-width from the
     residuals ``y_k - R x_k`` over ``sqrt(b) * mean(x_k)``."""
 
+    span = "query.totals"
+
     def __init__(self, aggregates, measure_of: dict, keys: list, ctx: _Ctx):
         self.aggregates = aggregates
         self.measure_of = measure_of
@@ -712,6 +716,9 @@ class _Totals:
         self.ctx = ctx
         self.counts: list[np.ndarray] = []
         self.sums: list[np.ndarray] = []
+
+    def fold(self, payload: dict, weight: float | None) -> None:
+        self.add(payload["totals"])
 
     def add(self, res) -> None:
         self.counts.append(res.counts)
@@ -758,6 +765,27 @@ class _Totals:
         return tuple(out), groups
 
 
+class _Aggregates:
+    """A float query's per-aggregate states behind the same interface as
+    :class:`_Totals`: ``fold`` one block's payload, then ``results()`` ->
+    ``(aggregates, groups)`` (``groups`` is None), timed as ``span``."""
+
+    span = "query.ci"
+
+    def __init__(self, aggregates, states):
+        self.pairs = list(zip(aggregates, states))
+
+    def fold(self, payload: dict, weight: float | None) -> None:
+        for agg, state in self.pairs:
+            if isinstance(state, _DistinctAgg):
+                state.merge_block(payload["distinct"])
+            else:
+                state.update(payload["per_class"] if agg.by_label else [payload["whole"]], weight)
+
+    def results(self) -> tuple[tuple[AggregateResult, ...], None]:
+        return tuple(state.result() for _, state in self.pairs), None
+
+
 def _stack_groups(values: list, by_label: bool):
     """Stack per-class results into a leading class axis (NaN for classes
     not yet observed); scalar-ize ungrouped single-element results."""
@@ -787,6 +815,91 @@ def _half_width(r: AggregateResult) -> float:
     ) / 2.0
     half = np.atleast_1d(half)
     return float(np.nanmax(half)) if np.any(~np.isnan(half)) else math.nan
+
+
+# ---------------------------------------------------------------------------
+# Per-block fold
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class _Fold:
+    """What a query folds from each block, fixed during set-up.
+
+    A float query runs one :class:`~repro.kernels.plan.QueryPlan` per
+    grouping it needs -- ``whole`` for its ungrouped aggregates,
+    ``per_class`` for its ``by_label`` ones, None where no aggregate needs
+    it -- with ``bins`` histogram bins on the ``lo`` / ``hi`` grid, and
+    with ``rows`` (distinct aggregates) a KMV sketch of the rows ``whole``
+    selects.  A typed query runs its ``typed`` plan.
+
+    The payload is a pure function of ``(block bytes, fold)`` -- no
+    draw-order or host-local state -- which is what makes distributed
+    execution bit-identical to single-host: any host computing this
+    block's payload produces the same dict, so *where* it is computed is
+    irrelevant to the fold."""
+
+    impl: str
+    whole: QueryPlan | None = None
+    per_class: QueryPlan | None = None
+    bins: int = 0
+    lo: np.ndarray | None = None
+    hi: np.ndarray | None = None
+    rows: bool = False
+    typed: TypedPlan | None = None
+
+    def payload(self, block) -> dict:
+        """Everything the fold needs from one block, as mergeable state."""
+        if self.typed is not None:
+            res = plan_totals(block, self.typed, impl=self.impl)
+            return {
+                "totals": res,
+                "rows_total": res.rows_total,
+                "rows_selected": res.rows_selected,
+            }
+        kw = dict(bins=self.bins, lo=self.lo, hi=self.hi, impl=self.impl)
+        whole = per_class = res = None
+        if self.whole is not None:
+            res = plan_sketch(block, self.whole, **kw)
+            whole = res.sketches[0]
+        if self.per_class is not None:
+            res_g = plan_sketch(block, self.per_class, **kw)
+            per_class = res_g.sketches
+            res = res if res is not None else res_g
+        return {
+            "whole": whole,
+            "per_class": per_class,
+            "rows_total": res.rows_total,
+            "rows_selected": res.rows_selected,
+            "distinct": _distinct_sketch(block, self.whole) if self.rows else None,
+        }
+
+    def fingerprint(self) -> bytes:
+        """The state a payload depends on beyond the query's shape: the
+        histogram grid (empty without one)."""
+        if self.lo is None:
+            return b""
+        return b"".join(
+            np.ascontiguousarray(np.asarray(v, np.float64)).tobytes()
+            for v in (self.lo, self.hi)
+        )
+
+
+def _distinct_sketch(block, plan: QueryPlan):
+    """Per-block KMV sketch of the rows ``plan`` selects, on its columns
+    (k-min of a union == union of k-mins, so folding these per-block
+    sketches is exactly the single-pass sketch of all surviving rows)."""
+    from repro.rsp.sketch import DistinctSketch
+
+    rows = np.asarray(block, dtype=np.float64)
+    rows = rows.reshape(rows.shape[0], -1)
+    if plan.predicates:
+        rows = rows[plan.mask(rows.astype(np.float32))]
+    if plan.columns is not None:
+        rows = rows[:, list(plan.resolve_columns(rows.shape[1]))]
+    sk = DistinctSketch()
+    if rows.size:
+        sk.update(rows)
+    return sk
 
 
 # ---------------------------------------------------------------------------
@@ -821,9 +934,6 @@ class QueryExecutor:
         )
         if any(a.by_label for a in query.aggregates) and dataset.num_classes is None:
             raise ValueError("by_label aggregates need num_classes on the dataset")
-        # where= / columns= route block passes through the plan-compiled
-        # fused kernels instead of the legacy whole-block sketch
-        self.planned = bool(query.where) or query.columns is not None
         # a typed query folds every block through one typed plan
         self.tplan = self._typed_plan() if self._is_typed() else None
 
@@ -1074,141 +1184,37 @@ class QueryExecutor:
         bad = ~np.isfinite(tlo) | ~np.isfinite(thi) | ~(thi > tlo)
         return np.where(bad, lo, tlo), np.where(bad, hi, thi)
 
-    def _make_states(self, needs_hist: bool):
-        ctx = _Ctx(
-            K=self.ds.num_blocks,
-            N=self.ds.spec.num_records,
-            confidence=self.q.confidence,
-            uniform=isinstance(self._pol, UniformPolicy),
-            num_classes=self.ds.num_classes,
-            bootstrap=self.q.bootstrap,
-            seed=self.seed,
-            filtered=bool(self.q.where),
-        )
-        lo = hi = None
-        if needs_hist:
-            lo, hi = self._grid()
+    def _setup(self, ctx: _Ctx) -> tuple[_Fold, _Aggregates | _Totals]:
+        """The query's fold description and the state its payloads fold
+        into: a typed plan and its totals, or the float plans and one state
+        per aggregate."""
+        q = self.q
+        if self.tplan is not None:
+            totals = _Totals(q.aggregates, self._measure_of, self._group_keys(), ctx)
+            return _Fold(q.sketch_impl, typed=self.tplan), totals
+        needs_hist = any(a.kind in ("quantile", "histogram") for a in q.aggregates)
+        lo, hi = self._grid() if needs_hist else (None, None)
         states = []
-        for a in self.q.aggregates:
+        for a in q.aggregates:
             if a.kind in ("quantile", "histogram"):
                 states.append(_HistAgg(a, ctx, lo, hi))
             elif a.kind == "distinct":
                 states.append(_DistinctAgg(a, ctx))
             else:
                 states.append(_MomentAgg(a, ctx))
-        return states, lo, hi
-
-    def _plan_sketches(self, block, lo, hi, needs_hist, grouped, need_whole) -> dict:
-        """Plan-compiled path for ``where=`` / ``columns=`` queries: one
-        fused filter+project+sketch pass per needed grouping, through the
-        plan compile cache and the shared autotuner."""
-        bins = self.q.bins if needs_hist else 0
-        kw = dict(bins=bins) if not needs_hist else dict(bins=bins, lo=lo, hi=hi)
-        whole = per_class = None
-        res = None
-        if need_whole:
-            res = plan_sketch(
-                block, self._plan(grouped=False), impl=self.q.sketch_impl, **kw
-            )
-            whole = res.sketches[0]
-        if grouped:
-            res_g = plan_sketch(
-                block, self._plan(grouped=True), impl=self.q.sketch_impl, **kw
-            )
-            per_class = res_g.sketches
-            res = res if res is not None else res_g
-        return {
-            "whole": whole,
-            "per_class": per_class,
-            "rows_total": res.rows_total,
-            "rows_selected": res.rows_selected,
-        }
-
-    def _block_sketches(self, block, lo, hi, needs_hist, grouped, need_whole) -> dict:
-        """One fused pass over the block; per-class sub-sketches on demand.
-        ``need_whole=False`` (every aggregate grouped) skips the dead
-        whole-block pass."""
-        from repro.kernels.block_sketch import block_sketch_ref
-
-        if self.planned:
-            return self._plan_sketches(block, lo, hi, needs_hist, grouped, need_whole)
-        bins = self.q.bins if needs_hist else 0
-        kw = dict(bins=bins) if not needs_hist else dict(bins=bins, lo=lo, hi=hi)
-        impl = self.q.sketch_impl
-        if bins == 0 and impl == "pallas":
-            impl = "jax"  # the kernel always histograms; moments-only goes jit
-        whole = block_sketch(block, impl=impl, **kw) if need_whole else None
-        per_class = None
-        if grouped:
-            x = np.asarray(block).reshape(np.shape(block)[0], -1)
-            labels = x[:, self.ds.label_column % x.shape[1]].astype(np.int64)
-            per_class = []
-            for c in range(self.ds.num_classes):
-                rows = x[labels == c]
-                if rows.shape[0] == 0:
-                    f = x.shape[1]
-                    per_class.append(
-                        BlockSketch(
-                            count=0.0,
-                            mean=np.zeros(f),
-                            m2=np.zeros(f),
-                            min=np.full(f, np.inf),
-                            max=np.full(f, -np.inf),
-                            hist=np.zeros((f, bins), np.int64) if needs_hist else None,
-                        )
-                    )
-                else:
-                    per_class.append(block_sketch_ref(rows, **kw))
-        n = int(np.shape(block)[0])
-        return {
-            "whole": whole, "per_class": per_class,
-            "rows_total": n, "rows_selected": n,
-        }
-
-    def _make_payload(
-        self, block, lo, hi, needs_hist, needs_rows, grouped, need_whole
-    ) -> dict:
-        """Everything the fold needs from one block, as mergeable state.
-
-        The payload is a pure function of ``(block bytes, query shape)`` --
-        no draw-order or host-local state -- which is what makes distributed
-        execution bit-identical to single-host: any host computing this
-        block's payload produces the same dict, so *where* it is computed is
-        irrelevant to the fold."""
-        if self.tplan is not None:
-            return {"totals": plan_totals(block, self.tplan, impl=self.q.sketch_impl)}
-        payload = self._block_sketches(block, lo, hi, needs_hist, grouped, need_whole)
-        payload["distinct"] = (
-            self._distinct_sketch(block) if needs_rows else None
+        by_label = [a.by_label for a in q.aggregates]
+        fold = _Fold(
+            q.sketch_impl,
+            whole=None if all(by_label) else self._plan(grouped=False),
+            per_class=self._plan(grouped=True) if any(by_label) else None,
+            bins=q.bins if needs_hist else 0,
+            lo=lo,
+            hi=hi,
+            rows=any(a.kind == "distinct" for a in q.aggregates),
         )
-        return payload
+        return fold, _Aggregates(q.aggregates, states)
 
-    def _distinct_sketch(self, block):
-        """Per-block KMV sketch of the filtered/projected rows (k-min of a
-        union == union of k-mins, so folding these per-block sketches is
-        exactly the single-pass sketch of all surviving rows)."""
-        from repro.rsp.sketch import DistinctSketch
-
-        q = self.q
-        rows = np.asarray(block, dtype=np.float64)
-        rows = rows.reshape(rows.shape[0], -1)
-        if q.where:
-            xf = rows.astype(np.float32)
-            keep = np.ones(rows.shape[0], dtype=bool)
-            for p in q.where:
-                keep &= p.mask(xf)
-            rows = rows[keep]
-        if q.columns is not None:
-            cols = [c % rows.shape[1] for c in q.columns]
-            rows = rows[:, cols]
-        sk = DistinctSketch()
-        if rows.size:
-            sk.update(rows)
-        return sk
-
-    def _payload_source(
-        self, ids, lo, hi, *, needs_hist, needs_rows, grouped, need_whole
-    ) -> Iterator[tuple[int, dict]]:
+    def _payload_source(self, ids, fold: _Fold) -> Iterator[tuple[int, dict]]:
         """Yield ``(block_id, payload)`` in selection order.
 
         This is the single seam between *selecting and computing* blocks and
@@ -1222,9 +1228,7 @@ class QueryExecutor:
         try:
             for bid, block in blocks:
                 with obs.span("query.fold", parent=self.ctx, block=bid):
-                    payload = self._make_payload(
-                        block, lo, hi, needs_hist, needs_rows, grouped, need_whole
-                    )
+                    payload = fold.payload(block)
                 yield bid, payload
         finally:
             blocks.close()  # stops the prefetches (see map_blocks)
@@ -1261,74 +1265,71 @@ class QueryExecutor:
                 yield res
                 return
 
-        if self.tplan is not None:
-            yield from self._totals_stream(anytime=anytime)
-            return
-
-        # sketch probabilities (weighted/stratified) and the histogram grid
-        # both come from ds.summaries, which on a sketch-less dataset reads
-        # every block -- those passes belong in this query's honest I/O count
-        if isinstance(q.policy, str) and q.policy != "uniform":
-            self._materialized_summaries()
         pol_kwargs = {}
-        if q.policy == "query_aware":
-            # hand the policy this query's shape: its predicates (KLL
-            # selectivity), the aggregated feature (dispersion), and
-            # whether it groups by label (class coverage)
-            feature = None
-            feats = {a.feature for a in q.aggregates if a.feature is not None}
-            if len(feats) == 1:
-                feature = next(iter(feats))
-                if q.columns is not None:  # map back to corpus column ids
-                    feature = q.columns[feature % len(q.columns)]
-            pol_kwargs = dict(
-                predicates=q.where,
-                feature=feature,
-                by_label=any(a.by_label for a in q.aggregates),
-            )
+        if self.tplan is None:
+            # sketch probabilities (weighted/stratified) and the histogram
+            # grid both come from ds.summaries, which on a sketch-less
+            # dataset reads every block -- those passes belong in this
+            # query's honest I/O count
+            if isinstance(q.policy, str) and q.policy != "uniform":
+                self._materialized_summaries()
+            if q.policy == "query_aware":
+                # hand the policy this query's shape: its predicates (KLL
+                # selectivity), the aggregated feature (dispersion), and
+                # whether it groups by label (class coverage)
+                feature = None
+                feats = {a.feature for a in q.aggregates if a.feature is not None}
+                if len(feats) == 1:
+                    feature = next(iter(feats))
+                    if q.columns is not None:  # map back to corpus column ids
+                        feature = q.columns[feature % len(q.columns)]
+                pol_kwargs = dict(
+                    predicates=q.where,
+                    feature=feature,
+                    by_label=any(a.by_label for a in q.aggregates),
+                )
         self._pol = self.ds.policy(q.policy, seed=self.seed, **pol_kwargs)
         uniform = isinstance(self._pol, UniformPolicy)
+        if self.tplan is not None and not uniform:
+            raise ValueError(
+                "typed totals expand uniform samples of blocks: use policy='uniform'"
+            )
         K = self.ds.num_blocks
         max_blocks = q.max_blocks if q.max_blocks is not None else K
         if uniform:
             max_blocks = min(max_blocks, K)
         if max_blocks < 1:
             raise ValueError("max_blocks must be >= 1")
-        needs_hist = any(a.kind in ("quantile", "histogram") for a in q.aggregates)
-        needs_rows = any(a.kind == "distinct" for a in q.aggregates)
-        grouped = any(a.by_label for a in q.aggregates)
-        need_whole = any(not a.by_label for a in q.aggregates)
+        ctx = _Ctx(
+            K=K,
+            N=self.ds.spec.num_records,
+            confidence=q.confidence,
+            uniform=uniform,
+            num_classes=self.ds.num_classes,
+            bootstrap=q.bootstrap,
+            seed=self.seed,
+            filtered=bool(q.where),
+        )
         with obs.span("query.setup", parent=self.ctx):
-            states, lo, hi = self._make_states(needs_hist)
+            fold, state = self._setup(ctx)
 
         def gen_ids():
             for _ in range(max_blocks):
                 yield self._pol.sample(1)[0]
 
         b = 0
-        filtered = bool(q.where)
         sel_rows = tot_rows = 0.0  # HT-weighted selectivity ratio estimator
         trace = ConvergenceTrace(confidence=q.confidence, target_rel_err=q.target_rel_err)
-        source = self._payload_source(
-            gen_ids(), lo, hi, needs_hist=needs_hist, needs_rows=needs_rows,
-            grouped=grouped, need_whole=need_whole,
-        )
+        source = self._payload_source(gen_ids(), fold)
         try:
-            for bid, sk in source:
+            for bid, payload in source:
                 weight = None
                 if isinstance(self._pol, WeightedPolicy):
                     weight = float(self._pol.weights([bid])[0])
-                if needs_rows:
-                    for state in states:
-                        if isinstance(state, _DistinctAgg):
-                            state.merge_block(sk["distinct"])
                 scale = weight if weight is not None else float(K)
-                sel_rows += scale * sk["rows_selected"]
-                tot_rows += scale * sk["rows_total"]
-                for agg, state in zip(q.aggregates, states):
-                    state.update(
-                        sk["per_class"] if agg.by_label else [sk["whole"]], weight
-                    )
+                sel_rows += scale * payload["rows_selected"]
+                tot_rows += scale * payload["rows_total"]
+                state.fold(payload, weight)
                 b += 1
                 # materializing results is not free (quantile CIs bootstrap
                 # over all b histograms); when nothing can stop the scan early
@@ -1340,8 +1341,8 @@ class QueryExecutor:
                 )
                 if not must_emit:
                     continue
-                with obs.span("query.ci", parent=self.ctx, blocks=b):
-                    results = tuple(s.result() for s in states)
+                with obs.span(state.span, parent=self.ctx, blocks=b):
+                    results, groups = state.results()
                 errs = [r.rel_err for r in results if r.rel_err is not None]
                 converged = (
                     q.target_rel_err is not None
@@ -1373,85 +1374,15 @@ class QueryExecutor:
                     converged=converged,
                     from_sketches=False,
                     executor_stats=self.counter.stats(),
-                    selectivity=(
-                        sel_rows / max(tot_rows, 1.0) if filtered else None
-                    ),
-                    trace=trace,
-                )
-                if converged:
-                    return
-        finally:
-            # GeneratorExit / convergence must reach the source's own finally
-            # (a distributed source publishes its stop marker there)
-            source.close()
-
-    def _totals_stream(self, *, anytime: bool) -> Iterator[QueryResult]:
-        """The progressive path of a typed query: one typed fold per block,
-        then every group's totals and intervals (``query.totals``)."""
-        q = self.q
-        self._pol = self.ds.policy(q.policy, seed=self.seed)
-        if not isinstance(self._pol, UniformPolicy):
-            raise ValueError("typed totals expand uniform samples of blocks: use policy='uniform'")
-        K = self.ds.num_blocks
-        max_blocks = min(q.max_blocks if q.max_blocks is not None else K, K)
-        if max_blocks < 1:
-            raise ValueError("max_blocks must be >= 1")
-        ctx = _Ctx(K=K, N=self.ds.spec.num_records, confidence=q.confidence, uniform=True,
-                   num_classes=None, bootstrap=q.bootstrap, seed=self.seed)
-        totals = _Totals(q.aggregates, self._measure_of, self._group_keys(), ctx)
-
-        def gen_ids():
-            for _ in range(max_blocks):
-                yield self._pol.sample(1)[0]
-
-        b = 0
-        sel_rows = tot_rows = 0
-        trace = ConvergenceTrace(confidence=q.confidence, target_rel_err=q.target_rel_err)
-        source = self._payload_source(gen_ids(), None, None, needs_hist=False, needs_rows=False,
-                                      grouped=False, need_whole=True)
-        try:
-            for bid, payload in source:
-                res = payload["totals"]
-                totals.add(res)
-                sel_rows += res.rows_selected
-                tot_rows += res.rows_total
-                b += 1
-                if not (anytime or q.explain or q.target_rel_err is not None or b == max_blocks):
-                    continue
-                with obs.span("query.totals", parent=self.ctx, blocks=b):
-                    results, groups = totals.results()
-                errs = [r.rel_err for r in results]
-                converged = (q.target_rel_err is not None and b >= q.min_blocks
-                             and max(errs) <= q.target_rel_err)
-                trace.record(
-                    ConvergenceStep(
-                        blocks_read=b,
-                        block_id=int(bid),
-                        max_rel_err=max(errs),
-                        estimates={r.name: _scalar0(r.estimate) for r in results},
-                        half_widths={r.name: _half_width(r) for r in results},
-                        cum_fetch_s=self.counter.fetch_seconds(),
-                        elapsed_s=time.perf_counter() - self._t0,
-                    )
-                )
-                if converged:
-                    source.close()
-                yield QueryResult(
-                    aggregates=results,
-                    blocks_read=b,
-                    total_blocks=K,
-                    confidence=q.confidence,
-                    target_rel_err=q.target_rel_err,
-                    converged=converged,
-                    from_sketches=False,
-                    executor_stats=self.counter.stats(),
-                    selectivity=sel_rows / max(tot_rows, 1) if q.where else None,
+                    selectivity=sel_rows / max(tot_rows, 1.0) if q.where else None,
                     trace=trace,
                     groups=groups,
                 )
                 if converged:
                     return
         finally:
+            # GeneratorExit / convergence must reach the source's own finally
+            # (a distributed source publishes its stop marker there)
             source.close()
 
     def run(self) -> QueryResult:

@@ -8,7 +8,7 @@ the single home for those sketches:
 * a :class:`Sketch` protocol -- ``update(rows)``, ``merge(other)``, versioned
   ``to_dict`` / ``from_dict`` -- with a registry of implementations,
 * :class:`MomentsSketch` (count / mean / M2 / extrema; wraps the same Chan
-  fold the ``block_sketch`` and ``plan`` kernels produce),
+  fold the ``block_sketch`` reference and ``plan`` kernels produce),
 * :class:`HistogramSketch` (mergeable fixed-grid histograms),
 * :class:`KLLSketch` (mergeable quantile sketch, Karnin-Lang-Liberty style),
 * :class:`DistinctSketch` (KMV / k-minimum-values distinct counting),
@@ -114,10 +114,9 @@ def _as_rows(rows) -> np.ndarray:
 @register_sketch
 class MomentsSketch(Sketch):
     """Count / per-feature mean / M2 / extrema.  The merge is the shared
-    :func:`repro.core.moments.chan_merge` -- the same fold the
-    ``block_sketch`` / ``plan`` kernels run on device, so kernel outputs
-    wrap into this sketch without recomputation
-    (:meth:`from_block_sketch`)."""
+    :func:`repro.core.moments.chan_merge` -- the same fold the ``plan``
+    kernels run on device, so kernel outputs wrap into this sketch without
+    recomputation (:meth:`wrap`)."""
 
     kind = "moments"
 
@@ -129,8 +128,8 @@ class MomentsSketch(Sketch):
         self.max = None if max is None else np.asarray(max, dtype=np.float64)
 
     @classmethod
-    def from_block_sketch(cls, sk) -> "MomentsSketch":
-        """Wrap a kernel-produced ``BlockSketch`` (no recompute)."""
+    def wrap(cls, sk) -> "MomentsSketch":
+        """Wrap a ``BlockSketch`` (no recompute)."""
         return cls(count=float(sk.count), mean=sk.mean, m2=sk.m2, min=sk.min, max=sk.max)
 
     def update(self, rows) -> "MomentsSketch":

@@ -140,7 +140,7 @@ def summarize_block(
             seed=seed,
             kinds=kinds,
         )
-        suite.sketches["moments"] = MomentsSketch.from_block_sketch(sk)
+        suite.sketches["moments"] = MomentsSketch.wrap(sk)
         by_column = np.asfortranarray(x)
         # KMV hashes a typed table's int32 codes by their own bits
         raw = np.asarray(block)

@@ -177,15 +177,15 @@ def test_auto_paths_deterministic_with_tuning_off(monkeypatch):
     """conftest pins REPRO_AUTOTUNE=off: impl="auto" entry points must not
     run measurements (tier-1 never depends on machine-local timings)."""
     monkeypatch.setenv("REPRO_AUTOTUNE", "off")
-    from repro.kernels.block_sketch import block_sketch
     from repro.kernels.plan import QueryPlan, plan_sketch
     from repro.kernels.rsp_shuffle import ops as rs_ops
 
     before = autotune.get_tuner().measurements
     x = np.random.default_rng(0).normal(size=(512, 4)).astype(np.float32)
 
-    a = block_sketch(x, bins=8, lo=-4.0, hi=4.0, impl="auto")
-    b = block_sketch(x, bins=8, lo=-4.0, hi=4.0, impl="ref")
+    whole = QueryPlan()
+    a = plan_sketch(x, whole, bins=8, lo=-4.0, hi=4.0, impl="auto").sketches[0]
+    b = plan_sketch(x, whole, bins=8, lo=-4.0, hi=4.0, impl="ref").sketches[0]
     np.testing.assert_allclose(a.mean, b.mean, rtol=1e-5, atol=1e-6)
 
     plan = QueryPlan(predicates="c0 > 0.0")

@@ -94,6 +94,12 @@ PLANS = {
         QueryPlan(predicates="c0 > 1.0", columns=(0, 1, 2), group_by=5, num_classes=3),
         3,
     ),
+    # the plans an unfiltered query folds through: the whole block, and one
+    # sketch per class (the block lacks class 3 in the last, grouped as the
+    # executor groups, by the label column counted from the end)
+    "whole": (QueryPlan(), 0),
+    "grouped": (QueryPlan(group_by=5, num_classes=3), 3),
+    "grouped_absent_class": (QueryPlan(group_by=-1, num_classes=4), 3),
 }
 
 
@@ -143,6 +149,37 @@ def test_plan_parity_no_hist(impl):
     x = _data()
     ref = plan_sketch(x, plan, impl="ref")
     _assert_matches(plan_sketch(x, plan, impl=impl, tile_rows=256), ref)
+
+
+@pytest.mark.parametrize("impl", ["ref", "np", "jax", "pallas"])
+def test_plan_out_of_range_mass_clipped(impl):
+    """Mass outside the grid clips into the edge bins: a histogram always
+    sums to the rows selected, per feature."""
+    rng = np.random.default_rng(13)
+    x = rng.normal(0.0, 5.0, size=(400, 2)).astype(np.float32)
+    sk = plan_sketch(x, QueryPlan(), bins=8, lo=-1.0, hi=1.0, impl=impl).sketches[0]
+    np.testing.assert_array_equal(sk.hist.sum(axis=1), [400, 400])
+    # bins are 0.25 wide: the first holds every value below -0.75, the last
+    # every value from 0.75 up
+    np.testing.assert_array_equal(sk.hist[:, 0], (x < -0.75).sum(0))
+    np.testing.assert_array_equal(sk.hist[:, -1], (x >= 0.75).sum(0))
+
+
+@pytest.mark.parametrize("impl", ["ref", "np", "jax", "pallas"])
+def test_plan_constant_feature_and_moments_only(impl):
+    x = np.concatenate(
+        [np.full((256, 1), 3.0, np.float32),
+         np.random.default_rng(14).normal(size=(256, 1)).astype(np.float32)],
+        axis=1,
+    )
+    sk = plan_sketch(x, QueryPlan(), bins=4, lo=x.min(0), hi=x.max(0), impl=impl).sketches[0]
+    assert sk.hist[0].tolist() == [256, 0, 0, 0]  # constant -> all mass bin 0
+    assert sk.hist[1].sum() == 256
+    # bins=0: moments only (the pallas kernel always histograms, so it runs jax)
+    m = plan_sketch(x, QueryPlan(), impl=impl).sketches[0]
+    assert m.hist is None
+    np.testing.assert_allclose(m.mean, x.mean(0), rtol=1e-6, atol=1e-6)
+    assert m.m2[0] == 0.0
 
 
 def test_plan_ragged_tiles():

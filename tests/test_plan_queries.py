@@ -28,6 +28,55 @@ def labelled_ds():
     return rsp.partition(data, blocks=k, seed=7, num_classes=2), data
 
 
+@pytest.mark.parametrize("impl", ["ref", "np", "jax", "pallas"])
+def test_unfiltered_and_grouped_folds_are_block_sketches(impl, monkeypatch):
+    """An unfiltered query folds each block through a plan with no
+    predicates, a grouped one through a plan grouped by label: the payload
+    is the sketch of the whole block, and of each class's rows, with a
+    class the block lacks as an empty sketch."""
+    from repro.kernels.block_sketch import block_sketch_ref
+    from repro.rsp.query import QueryExecutor
+
+    rng = np.random.default_rng(11)
+    x = rng.normal(1.5, 2.0, size=(1600, 3)).astype(np.float32)
+    y = rng.integers(0, 2, size=(1600, 1)).astype(np.float32)  # class 2 never occurs
+    ds = rsp.partition(np.concatenate([x, y], axis=1), blocks=4, seed=2, num_classes=3)
+    seen = []
+    source = QueryExecutor._payload_source
+
+    def spy(self, ids, fold):
+        for bid, payload in source(self, ids, fold):
+            seen.append((bid, fold, payload))
+            yield bid, payload
+
+    monkeypatch.setattr(QueryExecutor, "_payload_source", spy)
+    grouped_p50 = rsp.Aggregate("quantile", q=0.5, by_label=True)
+    ds.query(["mean", "p50", grouped_p50], use_sketches=False, max_blocks=2,
+             sketch_impl=impl, seed=1)
+    assert len(seen) == 2
+    for bid, fold, payload in seen:
+        block = np.asarray(ds.block(bid))
+        kw = dict(bins=fold.bins, lo=fold.lo, hi=fold.hi)
+        wants = [("whole", payload["whole"], block_sketch_ref(block, **kw))]
+        for c in range(2):
+            rows = block[block[:, -1] == c]
+            wants.append((f"class {c}", payload["per_class"][c], block_sketch_ref(rows, **kw)))
+        for name, got, want in wants:
+            assert got.count == want.count, name
+            np.testing.assert_allclose(got.mean, want.mean, rtol=1e-5, atol=1e-5, err_msg=name)
+            np.testing.assert_allclose(got.m2, want.m2, rtol=1e-4, atol=1e-3, err_msg=name)
+            np.testing.assert_allclose(got.min, want.min, rtol=1e-6, err_msg=name)
+            np.testing.assert_allclose(got.max, want.max, rtol=1e-6, err_msg=name)
+            # the bin-edge caveat: float32 and float64 binning may put a
+            # value on an edge in adjacent bins, never off the feature
+            np.testing.assert_array_equal(got.hist.sum(-1), want.hist.sum(-1), err_msg=name)
+            assert np.abs(got.hist - want.hist).sum() <= 2, name
+        empty = payload["per_class"][2]
+        assert empty.count == 0 and not empty.hist.any()
+        assert np.all(empty.min == np.inf) and np.all(empty.max == -np.inf)
+        assert payload["rows_total"] == payload["rows_selected"] == block.shape[0]
+
+
 def _masked(data, col, thresh):
     return data[data[:, col] > np.float32(thresh)].astype(np.float64)
 

@@ -146,7 +146,7 @@ def test_summarize_block_equals_compactor_suite():
 
     x = np.asarray(block, dtype=np.float64)
     want = SketchSuite.create(7, label_column=18, num_classes=2)
-    want.sketches["moments"] = MomentsSketch.from_block_sketch(block_sketch_ref(x))
+    want.sketches["moments"] = MomentsSketch.wrap(block_sketch_ref(x))
     kll = want.sketches["kll"]
     kll._columns = [
         _compactor_column(x[:, j], kll.k, (kll.seed << 8) + j) for j in range(x.shape[1])

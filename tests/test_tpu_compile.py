@@ -1,6 +1,6 @@
 """The main-path Pallas kernels compile for a TPU v5e chip.
 
-``block_sketch``, the fused ``plan`` kernel and ``rsp_shuffle`` are compiled
+The fused ``plan`` kernel and ``rsp_shuffle`` are compiled
 for a *described* v5e chip (no chip attached) at the widths of the HIGGS
 workload -- F = 29 columns (28 features + label), the query histogram's
 bins, one original block of N / P = 86,016 records -- with the tiles their
@@ -23,8 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.block_sketch import ops as bs_ops
-from repro.kernels.block_sketch.kernel import block_sketch_pallas
 from repro.kernels.plan import QueryPlan
 from repro.kernels.plan import ops as plan_ops
 from repro.kernels.plan.kernel import plan_sketch_pallas
@@ -65,19 +63,6 @@ def _no_compile_cache():
 def _compile_has_kernel(fn, *shapes):
     hlo = jax.jit(fn).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in hlo
-
-
-@pytest.mark.parametrize("bins", BINS)
-@pytest.mark.parametrize("tile", bs_ops.PALLAS_TILES)
-def test_block_sketch_compiles_for_v5e(one_chip, tile, bins):
-    x = jax.ShapeDtypeStruct((BLOCK_ROWS, F), jnp.float32, sharding=one_chip)
-    v = jax.ShapeDtypeStruct((F,), jnp.float32, sharding=one_chip)
-    _compile_has_kernel(
-        lambda x, lo, iw: block_sketch_pallas(
-            x, lo, iw, bins=bins, tile_rows=tile, interpret=False
-        ),
-        x, v, v,
-    )
 
 
 PLANS = {
@@ -201,18 +186,13 @@ def test_query_path_asks_for_compiled_kernels_on_tpu(monkeypatch, tmp_path):
 
     seen = []
 
-    def sketch_spy(*args, interpret, **kw):
-        seen.append(("block_sketch", interpret))
-        return block_sketch_pallas(*args, interpret=True, **kw)  # CPU can only interpret
-
     def plan_spy(*args, interpret, **kw):
         seen.append(("plan", interpret))
-        return plan_sketch_pallas(*args, interpret=True, **kw)
+        return plan_sketch_pallas(*args, interpret=True, **kw)  # CPU can only interpret
 
     data = _higgs_like(8 * 8 * 24)
     ds = rsp.partition(data, blocks=8, seed=0, backend="np", num_classes=2)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(bs_ops, "_sketch_pallas", sketch_spy)
     monkeypatch.setattr("repro.kernels.plan.kernel.plan_sketch_pallas", plan_spy)
     monkeypatch.setattr(autotune, "_TUNER", autotune.Autotuner(path=str(tmp_path / "t.json")))
     plan_ops.cache_clear()
@@ -226,7 +206,7 @@ def test_query_path_asks_for_compiled_kernels_on_tpu(monkeypatch, tmp_path):
     finally:
         plan_ops.cache_clear()
     kinds = {k for k, _ in seen}
-    assert kinds == {"block_sketch", "plan"}
+    assert kinds == {"plan"}
     assert all(interpret is False for _, interpret in seen)
 
 
